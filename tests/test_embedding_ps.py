@@ -76,6 +76,20 @@ def test_uniform_shuffle_balances_hot_range():
     assert counts.max() <= 3 * max(counts.mean(), 1)
 
 
+@pytest.mark.parametrize("rows", [64, 4295, 20_769, 1_000_033])
+def test_shuffle_pos_is_a_permutation(rows):
+    """Every logical id owns its own storage row, also where id * MULT
+    leaves uint32 (the Criteo fields' 20,769 rows)."""
+    pos = np.asarray(PS.shuffle_pos(jnp.arange(rows), rows))
+    assert pos.min() >= 0 and pos.max() < rows
+    assert np.unique(pos).size == rows
+    # the exact affine map, as Python integers
+    ids = np.array([0, 1, min(4294, rows - 1), rows // 2, rows - 1],
+                   np.int64)
+    want = (ids * PS._SHUFFLE_MULT + PS._SHUFFLE_ADD) % rows
+    np.testing.assert_array_equal(pos[ids], want)
+
+
 if HAVE_HYPOTHESIS:
     @settings(deadline=None, max_examples=15)
     @given(st.integers(0, 1 << 20), st.integers(4, 1000))

@@ -126,6 +126,16 @@ def test_backward_kernel_flag_matches_oracle():
                                    rtol=2e-6, atol=2e-6)
 
 
+@pytest.mark.parametrize("backend", ["dense", "host_lru"])
+def test_backward_kernel_refuses_an_optimizer_it_cannot_apply(backend):
+    """The kernel applies adagrad only: any other optimizer is refused when
+    the backend is built, never run through the jnp oracle instead."""
+    spec = EmbeddingSpec(rows=64, dim=16, optimizer="sgd", backend=backend,
+                         cache_rows=32, backward_kernel=True)
+    with pytest.raises(ValueError, match="adagrad only"):
+        BK.create_backend(spec)
+
+
 # ---------------------------------------------------------------------------
 # store_dtype at trainer level
 # ---------------------------------------------------------------------------

@@ -265,7 +265,7 @@ def _fused_backward_case(R, Dm, U, n_occ, seed, apply_self=False):
     acc = jnp.asarray(rng.random(R).astype(np.float32))
     inv = jnp.asarray(rng.integers(-1, U, n_occ), jnp.int32)
     grads = jnp.asarray(rng.standard_normal((n_occ, Dm)).astype(np.float32))
-    n_live = max(U // 2, 1)                      # half the plan is padding
+    n_live = min(max(U // 2, 1), R)              # half the plan is padding
     apply_idx = np.full(U, -1, np.int32)
     apply_idx[:n_live] = rng.permutation(R)[:n_live]
     apply_idx = jnp.asarray(apply_idx)
