@@ -55,6 +55,7 @@ traffic flows out through the trainer's per-step metrics.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import os
 import threading
@@ -72,6 +73,7 @@ from repro.core.embedding_ps import EmbeddingSpec
 from repro.core.hotness import HotnessSketch
 from repro.core.lru import LRUEmbeddingStore, STORE_DTYPES
 from repro.core.mmap_store import TieredHostStore
+from repro.core.spans import span
 from repro.utils import round_up
 
 
@@ -92,15 +94,17 @@ _pow2_bucket = D.pow2_bucket
 
 @jax.jit
 def _fault_apply(table, slot_ids, vslots, vecs, ids):
-    return (table.at[vslots].set(vecs.astype(table.dtype)),
-            slot_ids.at[vslots].set(ids))
+    with jax.named_scope("persia/fault"):
+        return (table.at[vslots].set(vecs.astype(table.dtype)),
+                slot_ids.at[vslots].set(ids))
 
 
 @jax.jit
 def _fault_apply_acc(table, slot_ids, acc, vslots, vecs, ids, accs):
-    return (table.at[vslots].set(vecs.astype(table.dtype)),
-            slot_ids.at[vslots].set(ids),
-            acc.at[vslots].set(accs))
+    with jax.named_scope("persia/fault"):
+        return (table.at[vslots].set(vecs.astype(table.dtype)),
+                slot_ids.at[vslots].set(ids),
+                acc.at[vslots].set(accs))
 
 
 @jax.jit
@@ -136,6 +140,12 @@ class EmbeddingBackend:
     # set by restore_from_checkpoint when the restored blob had a different
     # shard geometry than this backend (caches flushed, queues invalidated)
     last_restore_resharded: bool = False
+
+    @functools.cached_property
+    def span_s(self) -> dict[str, float]:
+        """Seconds of this table's host spans by name (``prepare/plan``,
+        ``prepare/slots``, ...; core/spans.py), over the backend's life."""
+        return {}
 
     # -- host-level ----------------------------------------------------------
     def init(self, key, shards: int = 1, scale: float = 0.02):
@@ -696,49 +706,47 @@ class HostLRUBackend(EmbeddingBackend):
 
     def _prepare_locked(self, state, ids, assume_unique: bool = False,
                         counts=None):
-        spec = self.spec
-        flat = np.asarray(ids, np.int64).reshape(-1)
-        valid = (flat >= 0) & (flat < spec.rows)
-        uniq = flat[valid] if assume_unique else np.unique(flat[valid])
-        if uniq.size > self.cache_rows:
-            raise ValueError(
-                f"batch working set ({uniq.size} unique ids) exceeds the "
-                f"device cache ({self.cache_rows} slots) — raise "
-                "EmbeddingSpec.cache_rows or shrink the batch")
-        self._tick += 1
-        smap = self._slot_for_id
-        if self._sketch is not None:
-            c = None
-            if counts is not None:
-                c = np.asarray(counts, np.float64).reshape(-1)
-                c = c[valid] if c.size == flat.size else None
-            self._sketch.update(uniq, c)
-        uslots = self._slot_arr[uniq].astype(np.int64)
-        self.last_admit = self.last_bypass = self.last_promote = 0
-        if self._sketch is not None:
+        # each stretch runs inside one span of core/spans.PREPARE_PHASES;
+        # the eviction calls between them time their own phases
+        spec, t = self.spec, self.span_s
+        with span("prepare/slots", t):
+            flat = np.asarray(ids, np.int64).reshape(-1)
+            valid = (flat >= 0) & (flat < spec.rows)
+            uniq = flat[valid] if assume_unique else np.unique(flat[valid])
+            if uniq.size > self.cache_rows:
+                raise ValueError(
+                    f"batch working set ({uniq.size} unique ids) exceeds "
+                    f"the device cache ({self.cache_rows} slots) — raise "
+                    "EmbeddingSpec.cache_rows or shrink the batch")
+            self._tick += 1
+            if self._sketch is not None:
+                c = None
+                if counts is not None:
+                    c = np.asarray(counts, np.float64).reshape(-1)
+                    c = c[valid] if c.size == flat.size else None
+                self._sketch.update(uniq, c)
+            uslots = self._slot_arr[uniq].astype(np.int64)
+            self.last_admit = self.last_bypass = self.last_promote = 0
+            promo = self._promotions(uniq, uslots)
+        if promo is not None:
             # promote bypass-resident rows that have become hot: write the
             # device copy (the freshest) back to the host store, free the
             # bypass slot, and let the normal fault path re-admit them into
-            # the main cache this same step — pinned slots (in-flight
-            # pipelined batches) wait for a later step
-            in_byp = uslots >= self.cache_rows
-            if in_byp.any():
-                hot = self._sketch.estimate(uniq) >= self.admit_threshold
-                safe = np.clip(uslots, 0, self.dev_slots - 1)
-                promo = in_byp & hot & (self._pin_count[safe] == 0)
-                if promo.any():
-                    state = dict(state)
-                    self._evict_slots(uslots[promo], state)
-                    uslots[promo] = -1
-                    self.last_promote = int(promo.sum())
-                    self.promotes += self.last_promote
+            # the main cache this same step
+            state = dict(state)
+            self._evict_slots(uslots[promo], state)
+            uslots[promo] = -1
+            self.last_promote = int(promo.sum())
+            self.promotes += self.last_promote
         hit_slots = uslots[uslots >= 0]
         missing = uniq[uslots < 0]
         self.hits += int(hit_slots.size)
         if missing.size:
             state = dict(state)
             if self._sketch is not None:
-                admit, bypass = self._split_admission(missing, hit_slots)
+                with span("prepare/slots", t):
+                    admit, bypass = self._split_admission(missing,
+                                                          hit_slots)
                 v_main = self._free_slots(hit_slots, admit.size, state,
                                           hi=self.cache_rows)
                 v_byp = self._free_slots(hit_slots, bypass.size, state,
@@ -753,43 +761,68 @@ class HostLRUBackend(EmbeddingBackend):
                 victims = self._free_slots(hit_slots, missing.size, state)
                 self.admits += int(missing.size)
                 self.last_admit = int(missing.size)
-            vecs, accs = self.store.read_rows(missing)
-            self.faults += missing.size
-            # bucket the scatter shape (see _pow2_bucket): pad slots index
-            # one past the cache — an out-of-bounds scatter update, which
-            # JAX drops — so padding never touches a real row
-            m, bucket = missing.size, _pow2_bucket(missing.size)
-            pad_slots = np.full(bucket, self.dev_slots, np.int64)
-            pad_slots[:m] = victims
-            pad_vecs = np.zeros((bucket, spec.dim), np.float32)
-            pad_vecs[:m] = vecs
-            pad_ids = np.full(bucket, -1, np.int64)
-            pad_ids[:m] = missing
-            vslots = jnp.asarray(pad_slots, jnp.int32)
-            vecs_j = jnp.asarray(pad_vecs, jnp.float32)
-            ids_j = jnp.asarray(pad_ids, jnp.int32)
-            if "acc" in state:
-                pad_accs = np.zeros(bucket, np.float32)
-                pad_accs[:m] = accs
-                state["table"], state["slot_ids"], state["acc"] = \
-                    _fault_apply_acc(state["table"], state["slot_ids"],
-                                     state["acc"], vslots, vecs_j, ids_j,
-                                     jnp.asarray(pad_accs, jnp.float32))
-            else:
-                state["table"], state["slot_ids"] = _fault_apply(
-                    state["table"], state["slot_ids"], vslots, vecs_j, ids_j)
-            for k, s in zip(missing.tolist(), victims.tolist()):
-                smap[k] = s
-            self._slot_arr[missing] = victims
-            self._id_for_slot[victims] = missing
-            touched = np.concatenate([hit_slots, victims])
+            m = int(missing.size)
+            with span("prepare/store", t, rows=m):
+                vecs, accs = self.store.read_rows(missing)
+            self.faults += m
+            with span("prepare/fault_h2d", t, rows=m):
+                self._fault_rows(state, victims, missing, vecs, accs)
+            with span("prepare/slots", t):
+                smap = self._slot_for_id
+                for k, s in zip(missing.tolist(), victims.tolist()):
+                    smap[k] = s
+                self._slot_arr[missing] = victims
+                self._id_for_slot[victims] = missing
+                touched = np.concatenate([hit_slots, victims])
         else:
             touched = hit_slots
-        self._slot_clock[touched] = self._tick
-        dev = np.where(valid,
-                       self._slot_arr[np.where(valid, flat, 0)].astype(
-                           np.int64), -1)
-        return state, jnp.asarray(dev.reshape(np.shape(ids)), jnp.int32)
+        with span("prepare/slots", t):
+            self._slot_clock[touched] = self._tick
+            dev = np.where(valid,
+                           self._slot_arr[np.where(valid, flat, 0)].astype(
+                               np.int64), -1)
+            return state, jnp.asarray(dev.reshape(np.shape(ids)), jnp.int32)
+
+    def _promotions(self, uniq: np.ndarray, uslots: np.ndarray):
+        """Mask over ``uniq`` of bypass-resident rows hot enough to move
+        into the main cache (pinned slots — in-flight pipelined batches —
+        wait for a later step), or None."""
+        if self._sketch is None:
+            return None
+        in_byp = uslots >= self.cache_rows
+        if not in_byp.any():
+            return None
+        hot = self._sketch.estimate(uniq) >= self.admit_threshold
+        safe = np.clip(uslots, 0, self.dev_slots - 1)
+        promo = in_byp & hot & (self._pin_count[safe] == 0)
+        return promo if promo.any() else None
+
+    def _fault_rows(self, state, victims, missing, vecs, accs):
+        """Copy the faulted rows to the device and scatter them into their
+        victim slots of ``state`` (updated in place)."""
+        # bucket the scatter shape (see _pow2_bucket): pad slots index one
+        # past the cache — an out-of-bounds scatter update, which JAX
+        # drops — so padding never touches a real row
+        m, bucket = missing.size, _pow2_bucket(missing.size)
+        pad_slots = np.full(bucket, self.dev_slots, np.int64)
+        pad_slots[:m] = victims
+        pad_vecs = np.zeros((bucket, self.spec.dim), np.float32)
+        pad_vecs[:m] = vecs
+        pad_ids = np.full(bucket, -1, np.int64)
+        pad_ids[:m] = missing
+        vslots = jnp.asarray(pad_slots, jnp.int32)
+        vecs_j = jnp.asarray(pad_vecs, jnp.float32)
+        ids_j = jnp.asarray(pad_ids, jnp.int32)
+        if "acc" in state:
+            pad_accs = np.zeros(bucket, np.float32)
+            pad_accs[:m] = accs
+            state["table"], state["slot_ids"], state["acc"] = \
+                _fault_apply_acc(state["table"], state["slot_ids"],
+                                 state["acc"], vslots, vecs_j, ids_j,
+                                 jnp.asarray(pad_accs, jnp.float32))
+        else:
+            state["table"], state["slot_ids"] = _fault_apply(
+                state["table"], state["slot_ids"], vslots, vecs_j, ids_j)
 
     def _free_slots(self, protected: np.ndarray, need: int, state,
                     lo: int = 0, hi: int | None = None):
@@ -804,29 +837,30 @@ class HostLRUBackend(EmbeddingBackend):
             hi = self.dev_slots
         if need <= 0:
             return np.zeros(0, np.int64)
-        in_region = np.zeros(self.dev_slots, bool)
-        in_region[lo:hi] = True
-        pinned = self._pin_count > 0
-        free = np.nonzero((self._id_for_slot < 0) & ~pinned
-                          & in_region)[0][:need]
-        n_evict = need - free.size
-        if n_evict <= 0:
-            return free
-        cand = in_region.copy()
-        cand[self._id_for_slot < 0] = False
-        cand[protected] = False
-        cand[pinned] = False
-        cand_slots = np.nonzero(cand)[0]
-        if cand_slots.size < n_evict:
-            raise ValueError(
-                f"fault-in needs {n_evict} eviction victims but only "
-                f"{cand_slots.size} unpinned slots are evictable: the "
-                f"combined working set of in-flight pipelined batches "
-                f"exceeds the device cache ({hi - lo} slots in "
-                f"[{lo}, {hi}), {int(pinned.sum())} pinned) — lower "
-                "max_inflight or raise EmbeddingSpec.cache_rows")
-        order = np.argsort(self._slot_clock[cand_slots], kind="stable")
-        evict = cand_slots[order[:n_evict]]
+        with span("prepare/slots", self.span_s):
+            in_region = np.zeros(self.dev_slots, bool)
+            in_region[lo:hi] = True
+            pinned = self._pin_count > 0
+            free = np.nonzero((self._id_for_slot < 0) & ~pinned
+                              & in_region)[0][:need]
+            n_evict = need - free.size
+            if n_evict <= 0:
+                return free
+            cand = in_region.copy()
+            cand[self._id_for_slot < 0] = False
+            cand[protected] = False
+            cand[pinned] = False
+            cand_slots = np.nonzero(cand)[0]
+            if cand_slots.size < n_evict:
+                raise ValueError(
+                    f"fault-in needs {n_evict} eviction victims but only "
+                    f"{cand_slots.size} unpinned slots are evictable: the "
+                    f"combined working set of in-flight pipelined batches "
+                    f"exceeds the device cache ({hi - lo} slots in "
+                    f"[{lo}, {hi}), {int(pinned.sum())} pinned) — lower "
+                    "max_inflight or raise EmbeddingSpec.cache_rows")
+            order = np.argsort(self._slot_clock[cand_slots], kind="stable")
+            evict = cand_slots[order[:n_evict]]
         self._evict_slots(evict, state)
         return np.concatenate([free, evict])
 
@@ -834,25 +868,29 @@ class HostLRUBackend(EmbeddingBackend):
         """Write the given occupied slots' rows (vector + acc — the device
         copy is the freshest) back to the host store and clear their slot
         bookkeeping. Callers pick the victims; this does the writeback."""
+        t = self.span_s
         n_evict = int(evict.size)
         ev_ids = self._id_for_slot[evict]
-        # bucketed gather (see _pow2_bucket); pad rows are sliced back off
-        idx = np.zeros(_pow2_bucket(n_evict), np.int64)
-        idx[:n_evict] = evict
-        eslots = jnp.asarray(idx, jnp.int32)
-        if "acc" in state:
-            vecs_j, accs_j = _gather_rows_acc(state["table"], state["acc"],
-                                              eslots)
-            accs = np.asarray(accs_j)[:n_evict]
-        else:
-            vecs_j, accs = _gather_rows(state["table"], eslots), None
-        vecs = np.asarray(vecs_j)[:n_evict]
-        self.store.write_rows(ev_ids, vecs, accs)
+        with span("prepare/evict_d2h", t, rows=n_evict):
+            # bucketed gather (see _pow2_bucket); pad rows are sliced off
+            idx = np.zeros(_pow2_bucket(n_evict), np.int64)
+            idx[:n_evict] = evict
+            eslots = jnp.asarray(idx, jnp.int32)
+            if "acc" in state:
+                vecs_j, accs_j = _gather_rows_acc(state["table"],
+                                                  state["acc"], eslots)
+                accs = np.asarray(accs_j)[:n_evict]
+            else:
+                vecs_j, accs = _gather_rows(state["table"], eslots), None
+            vecs = np.asarray(vecs_j)[:n_evict]
+        with span("prepare/store", t, rows=n_evict):
+            self.store.write_rows(ev_ids, vecs, accs)
         self.writebacks += n_evict
-        for k in ev_ids.tolist():
-            del self._slot_for_id[k]
-        self._slot_arr[ev_ids] = -1
-        self._id_for_slot[evict] = -1
+        with span("prepare/slots", t):
+            for k in ev_ids.tolist():
+                del self._slot_for_id[k]
+            self._slot_arr[ev_ids] = -1
+            self._id_for_slot[evict] = -1
 
     # -- slot pinning (pipelined callers) ------------------------------------
     #
@@ -1048,8 +1086,8 @@ class HostLRUBackend(EmbeddingBackend):
         old_ids = jnp.take(queue["ids"], ptr, axis=0)
         old_g = jnp.take(queue["grads"], ptr, axis=0)
         old_safe = jnp.clip(old_slots, 0, self.dev_slots - 1)
-        still = (old_slots >= 0) & (old_ids >= 0) & \
-            (state["slot_ids"][old_safe] == old_ids)
+        popped = (old_slots >= 0) & (old_ids >= 0)
+        still = popped & (state["slot_ids"][old_safe] == old_ids)
         new, g_push = _fused_backward(spec, state, plan.inv, grads,
                                       jnp.where(still, old_slots, -1),
                                       old_g)
@@ -1065,7 +1103,8 @@ class HostLRUBackend(EmbeddingBackend):
             "ptr": (ptr + 1) % tau,
             "filled": jnp.minimum(queue["filled"] + 1, tau),
         }
-        return new, new_q, {}
+        # rows of the popped put whose slot was recycled: the lost puts
+        return new, new_q, {"lost_rows": jnp.sum(popped & ~still)}
 
     def _hybrid_unique(self, state, queue, slots_u, g_u):
         spec = self.spec
@@ -2122,8 +2161,10 @@ def prepare_all(backends, states, ids):
             submitted.append((n, None,
                               b.prepare_submit(states[n], ids[n])))
             continue
-        cap = D.dedup_cap(max(int(np.size(ids[n])), 1), b.dedup_rows())
-        u_pad, inv, counts, info = D.make_plan(ids[n], spec.rows, cap)
+        with span("prepare/plan", b.span_s, table=n):
+            cap = D.dedup_cap(max(int(np.size(ids[n])), 1),
+                              b.dedup_rows())
+            u_pad, inv, counts, info = D.make_plan(ids[n], spec.rows, cap)
         submitted.append((n, (inv, info),
                           b.prepare_submit(states[n], u_pad,
                                            assume_unique=True,
@@ -2138,8 +2179,9 @@ def prepare_all(backends, states, ids):
             continue
         inv, info = plan
         new_states[n], dev_u = collect()
-        dev_ids[n] = DedupPlan(dev=jnp.asarray(dev_u, jnp.int32),
-                               inv=jnp.asarray(inv, jnp.int32))
+        with span("prepare/plan", b.span_s, table=n):
+            dev_ids[n] = DedupPlan(dev=jnp.asarray(dev_u, jnp.int32),
+                                   inv=jnp.asarray(inv, jnp.int32))
         itemsize = jnp.dtype(spec.dtype).itemsize
         metrics[f"dedup/{n}/dup_factor"] = info["dup_factor"]
         metrics[f"dedup/{n}/unique_rows"] = float(info["n_unique"])
@@ -2155,6 +2197,7 @@ def _tag(metrics, name, table_metrics):
         metrics[f"wire/{name}/{k}"] = v
 
 
+@jax.named_scope("persia/lookup")
 def lookup_all(backends, states, dev_ids):
     """Traceable fan-out of per-table lookups -> (acts, wire metrics)."""
     acts, metrics = {}, {}
@@ -2167,14 +2210,39 @@ def lookup_all(backends, states, dev_ids):
     return acts, metrics
 
 
+@jax.named_scope("persia/put")
 def put_all(backends, states, queues, dev_ids, grads):
     """Traceable fan-out of per-table hybrid updates (push this step's put,
-    apply the tau-stale one) -> (states, queues, wire metrics)."""
+    apply the tau-stale one) -> (states, queues, metrics): the wire
+    metrics, and ``put/lost_rows``, the queued put rows dropped this step
+    because their cache slot was recycled (host_lru tables; summed)."""
     queues = queues or {}
     new_states, new_queues, metrics = dict(states), dict(queues), {}
+    lost = []
     for n in dev_ids:
         st, q, m = backends[n].hybrid_update(
             states[n], queues.get(n), dev_ids[n], grads[n])
         new_states[n], new_queues[n] = st, q
+        m = dict(m)
+        if "lost_rows" in m:
+            lost.append(m.pop("lost_rows"))
         _tag(metrics, n, m)
+    if lost:
+        metrics["put/lost_rows"] = sum(lost)
     return new_states, new_queues, metrics
+
+
+def span_seconds(backends) -> dict[str, float]:
+    """Each host span's seconds (``EmbeddingBackend.span_s``) summed over
+    the tables, their wire decorators and their shards."""
+    out: dict[str, float] = {}
+    todo = list(backends.values())
+    while todo:
+        b = todo.pop()
+        for k, v in b.span_s.items():
+            out[k] = out.get(k, 0.0) + v
+        if isinstance(b, CompressedWireBackend):
+            todo.append(b.inner)
+        elif isinstance(b, ShardedBackend):
+            todo.extend(b.shard_backends)
+    return out

@@ -39,6 +39,7 @@ from repro.core import backend as BK
 from repro.core import embedding_ps as PS
 from repro.core.collection import EmbeddingCollection
 from repro.core.embedding_ps import EmbeddingSpec
+from repro.core.spans import span
 
 
 @dataclass(frozen=True)
@@ -332,7 +333,8 @@ class PersiaTrainer:
                                           dev_ids)                # Alg.1 fwd
 
         def loss_fn(dense, acts_):
-            return adapter.loss(dense, acts_, batch)
+            with jax.named_scope("persia/tower"):
+                return adapter.loss(dense, acts_, batch)
 
         (loss, metrics), (dgrads, agrads) = jax.value_and_grad(
             loss_fn, argnums=(0, 1), has_aux=True)(state.dense, acts)
@@ -364,13 +366,17 @@ class PersiaTrainer:
     def step(self, state: TrainState, batch):
         """Fused step through a cached jit; donates ``state``. The host
         prepare phase (batch dedup + out-of-core fault-in) runs before the
-        jitted program."""
-        state, dev_ids, prep_m = self._prepare(state, batch)
-        if self._fused is None:
-            self._fused = jax.jit(self.train_step, donate_argnums=(0,))
-        state, metrics = self._fused(state, batch, dev_ids)
-        metrics.update(prep_m)
-        metrics.update(BK.shard_step_metrics(self.backends))
+        jitted program. Host spans: ``persia/step`` over the call,
+        ``persia/prepare`` and ``persia/step/dispatch`` inside it."""
+        with span("step"):
+            with span("prepare"):
+                state, dev_ids, prep_m = self._prepare(state, batch)
+            if self._fused is None:
+                self._fused = jax.jit(self.train_step, donate_argnums=(0,))
+            with span("step/dispatch"):
+                state, metrics = self._fused(state, batch, dev_ids)
+            metrics.update(prep_m)
+            metrics.update(BK.shard_step_metrics(self.backends))
         return state, metrics
 
     # -- decomposed pipeline ---------------------------------------------------
@@ -397,7 +403,8 @@ class PersiaTrainer:
         @partial(jax.jit, donate_argnums=(0, 1, 2))
         def dense_step(dense, opt, dense_queue, acts, batch, step_no):
             def loss_fn(dense_, acts_):                        # Alg.2
-                return adapter.loss(dense_, acts_, batch)
+                with jax.named_scope("persia/tower"):
+                    return adapter.loss(dense_, acts_, batch)
 
             (loss, metrics), (dgrads, agrads) = jax.value_and_grad(
                 loss_fn, argnums=(0, 1), has_aux=True)(dense, acts)

@@ -98,6 +98,7 @@ from typing import Any, Callable, Iterable, Optional
 from repro.core import backend as BK
 from repro.core.dedup import plan_dev
 from repro.core.hybrid import PersiaTrainer, TrainState
+from repro.core.spans import PREPARE_PHASES, span
 
 STAGES = ("loader", "prefetch", "prepare", "lookup", "dense", "put")
 
@@ -118,10 +119,10 @@ class PipelineStageError(RuntimeError):
 
 
 class _StageStats:
-    """Per-stage busy time + items + input-queue depth accounting."""
+    """Per-stage items + input-queue depth accounting (busy time is the
+    ``stage/<stage>`` span, kept in ``PipelinedTrainer._span_s``)."""
 
     def __init__(self):
-        self.busy_s = 0.0
         self.items = 0
         self.depth_max = 0
         self.depth_sum = 0
@@ -177,6 +178,11 @@ class PipelinedTrainer:
         self.prefetch = int(prefetch)
         self.delay_fn = delay_fn
         self._stats: dict[str, _StageStats] = {}
+        # seconds of the last run's host spans by name: the stages' busy
+        # intervals, the store-lock wait, and each backend's prepare phases
+        # (core/spans.py)
+        self._span_s: dict[str, float] = {}
+        self._lost_rows = None    # device scalar: the last run's lost puts
         self._wall_s = 0.0
         self._steps_done = 0
         self.max_outstanding: dict[str, int] = {}
@@ -284,6 +290,9 @@ class PipelinedTrainer:
         # the prefetch stage is a passthrough and the permit is unused.
         prefetch_sem = threading.Semaphore(self.max_inflight + self.prefetch)
         self._stats = {s: _StageStats() for s in STAGES}
+        spans = self._span_s = {}
+        self._lost_rows = None
+        phases0 = BK.span_seconds(backends)
         qs = {s: queue.Queue(maxsize=self.max_inflight)
               for s in ("prefetch", "lookup", "dense", "put")}
         # the prepare queue buffers the look-ahead: faulted batches wait
@@ -337,9 +346,8 @@ class PipelinedTrainer:
                         break
                     if stop.is_set():
                         return
-                    t0 = time.perf_counter()
-                    sleep_for("loader", idx)
-                    st.busy_s += time.perf_counter() - t0
+                    with span("stage/loader", spans):
+                        sleep_for("loader", idx)
                     st.items += 1
                     if not q_put("prefetch", (idx, batch)):
                         return
@@ -374,12 +382,17 @@ class PipelinedTrainer:
             fresh host-built arrays — not between the lookup stage's
             window acquire and its jitted dispatch."""
             ids = adapter.emb_ids(batch)
-            with store_lock:
+            with span("prepare/lock_wait", spans):
+                store_lock.acquire()
+            try:
                 emb, dev_ids, prep_m = BK.prepare_all(
                     backends, store["emb"], ids)
                 store["emb"] = emb
                 for n in dev_ids:
-                    backends[n].pin_slots(plan_dev(dev_ids[n]))
+                    with span("prepare/slots", backends[n].span_s, table=n):
+                        backends[n].pin_slots(plan_dev(dev_ids[n]))
+            finally:
+                store_lock.release()
             touched = {n: touched_shards(n, dev_ids) for n in names}
             return dev_ids, touched, prep_m
 
@@ -405,10 +418,9 @@ class PipelinedTrainer:
                 try:
                     if not acquire(prefetch_sem):
                         return
-                    t0 = time.perf_counter()
-                    sleep_for("prefetch", idx)
-                    dev_ids, touched, prep_m = fault_in(batch)
-                    st.busy_s += time.perf_counter() - t0
+                    with span("stage/prefetch", spans):
+                        sleep_for("prefetch", idx)
+                        dev_ids, touched, prep_m = fault_in(batch)
                     st.items += 1
                     if not q_put("prepare", (idx, batch, dev_ids, touched,
                                              prep_m)):
@@ -433,13 +445,12 @@ class PipelinedTrainer:
                     # permit this pins the exact serial dispatch order.
                     if not acquire(inflight):
                         return
-                    t0 = time.perf_counter()
-                    sleep_for("prepare", idx)
-                    if len(item) == 2:
-                        dev_ids, touched, prep_m = fault_in(batch)
-                    else:          # already faulted by the prefetch stage
-                        _, _, dev_ids, touched, prep_m = item
-                    st.busy_s += time.perf_counter() - t0
+                    with span("stage/prepare", spans):
+                        sleep_for("prepare", idx)
+                        if len(item) == 2:
+                            dev_ids, touched, prep_m = fault_in(batch)
+                        else:      # already faulted by the prefetch stage
+                            _, _, dev_ids, touched, prep_m = item
                     st.items += 1
                     if not q_put("lookup", (idx, batch, dev_ids, touched,
                                             prep_m)):
@@ -459,23 +470,22 @@ class PipelinedTrainer:
                     return
                 idx, batch, dev_ids, touched, prep_m = item
                 try:
-                    t0 = time.perf_counter()
-                    sleep_for("lookup", idx)
-                    # staleness backpressure: block (never drop) until every
-                    # (table, shard) this batch charges is within its put
-                    # window (see touched_shards for what a batch charges)
-                    for n in names:
-                        for s in touched[n]:
-                            if not acquire(windows[(n, s)]):
-                                return
-                    with out_lock:
+                    with span("stage/lookup", spans):
+                        sleep_for("lookup", idx)
+                        # staleness backpressure: block (never drop) until
+                        # every (table, shard) this batch charges is within
+                        # its put window (see touched_shards)
                         for n in names:
-                            outstanding[n] += 1
-                            self.max_outstanding[n] = max(
-                                self.max_outstanding[n], outstanding[n])
-                    with store_lock:
-                        acts, get_m = lookup_fn(store["emb"], dev_ids)
-                    st.busy_s += time.perf_counter() - t0
+                            for s in touched[n]:
+                                if not acquire(windows[(n, s)]):
+                                    return
+                        with out_lock:
+                            for n in names:
+                                outstanding[n] += 1
+                                self.max_outstanding[n] = max(
+                                    self.max_outstanding[n], outstanding[n])
+                        with store_lock:
+                            acts, get_m = lookup_fn(store["emb"], dev_ids)
                     st.items += 1
                     if not q_put("dense", (idx, batch, dev_ids, acts, get_m,
                                            touched, prep_m)):
@@ -495,15 +505,14 @@ class PipelinedTrainer:
                     return
                 idx, batch, dev_ids, acts, get_m, touched, prep_m = item
                 try:
-                    t0 = time.perf_counter()
-                    sleep_for("dense", idx)
-                    d = dense_cell
-                    dense, opt, dq, agrads, metrics = dense_step(
-                        d["dense"], d["opt"], d["queue"], acts, batch,
-                        d["step"])
-                    dense_cell.update(dense=dense, opt=opt, queue=dq,
-                                      step=d["step"] + 1)
-                    st.busy_s += time.perf_counter() - t0
+                    with span("stage/dense", spans):
+                        sleep_for("dense", idx)
+                        d = dense_cell
+                        dense, opt, dq, agrads, metrics = dense_step(
+                            d["dense"], d["opt"], d["queue"], acts, batch,
+                            d["step"])
+                        dense_cell.update(dense=dense, opt=opt, queue=dq,
+                                          step=d["step"] + 1)
                     st.items += 1
                     if not q_put("put", (idx, dev_ids, agrads,
                                          metrics, get_m, touched, prep_m)):
@@ -520,32 +529,38 @@ class PipelinedTrainer:
                     return
                 idx, dev_ids, agrads, metrics, get_m, touched, prep_m = item
                 try:
-                    t0 = time.perf_counter()
-                    sleep_for("put", idx)
-                    with store_lock:
-                        emb, queues, put_m = emb_put(
-                            store["emb"], store["queues"], dev_ids, agrads)
-                        store["emb"] = emb
-                        store["queues"] = queues
-                        for n in dev_ids:
-                            backends[n].unpin_slots(plan_dev(dev_ids[n]))
-                    self.applied_order.append(idx)
-                    with out_lock:
+                    with span("stage/put", spans):
+                        sleep_for("put", idx)
+                        with store_lock:
+                            emb, queues, put_m = emb_put(
+                                store["emb"], store["queues"], dev_ids,
+                                agrads)
+                            store["emb"] = emb
+                            store["queues"] = queues
+                            for n in dev_ids:
+                                backends[n].unpin_slots(
+                                    plan_dev(dev_ids[n]))
+                        self.applied_order.append(idx)
+                        with out_lock:
+                            for n in names:
+                                outstanding[n] -= 1
                         for n in names:
-                            outstanding[n] -= 1
-                    for n in names:
-                        for s in touched[n]:
-                            windows[(n, s)].release()
-                    inflight.release()
-                    if self.prefetch > 0:
-                        prefetch_sem.release()
-                    merged = dict(metrics)
-                    merged.update(prep_m)
-                    merged.update(get_m)
-                    merged.update(put_m)
-                    merged.update(BK.shard_step_metrics(backends))
-                    results.append((idx, merged))
-                    st.busy_s += time.perf_counter() - t0
+                            for s in touched[n]:
+                                windows[(n, s)].release()
+                        inflight.release()
+                        if self.prefetch > 0:
+                            prefetch_sem.release()
+                        if "put/lost_rows" in put_m:
+                            # one running device scalar: no host read here
+                            lost = put_m["put/lost_rows"]
+                            self._lost_rows = lost if self._lost_rows is \
+                                None else self._lost_rows + lost
+                        merged = dict(metrics)
+                        merged.update(prep_m)
+                        merged.update(get_m)
+                        merged.update(put_m)
+                        merged.update(BK.shard_step_metrics(backends))
+                        results.append((idx, merged))
                     st.items += 1
                 except Exception as e:   # noqa: BLE001
                     fail("put", idx, e)
@@ -579,6 +594,8 @@ class PipelinedTrainer:
             for b in backends.values():
                 b.reset_pins()
             self._wall_s = time.perf_counter() - t_wall
+            for k, v in BK.span_seconds(backends).items():
+                spans[k] = spans.get(k, 0.0) + v - phases0.get(k, 0.0)
             self._steps_done = len(results)
             self._running = False
         if errors:
@@ -594,9 +611,14 @@ class PipelinedTrainer:
     # -- per-stage metrics ----------------------------------------------------
 
     def pipeline_metrics(self) -> dict[str, float]:
-        """Timing/occupancy of the last ``run()``: per-stage busy seconds,
-        occupancy (busy/wall), items, and input-queue depth stats, plus
-        the run-level wall time and steps/s."""
+        """Timing/occupancy of the last ``run()``: per-stage busy seconds
+        (the ``persia/stage/<stage>`` spans), occupancy (busy/wall), items,
+        and input-queue depth stats; the host fault-in's seconds per phase
+        (``pipeline/prepare/<phase>_s``, the ``persia/prepare/<phase>``
+        spans summed over tables; with ``prefetch`` > 0 the fault-in runs
+        in the prefetch stage); the queued put rows lost to a recycled
+        cache slot (``pipeline/put/lost_rows``); and the run-level wall
+        time and steps/s."""
         wall = max(self._wall_s, 1e-9)
         out: dict[str, float] = {
             "pipeline/wall_s": self._wall_s,
@@ -606,14 +628,20 @@ class PipelinedTrainer:
             "pipeline/prefetch": float(self.prefetch),
         }
         for stage, st in self._stats.items():
-            out[f"pipeline/{stage}/busy_s"] = st.busy_s
-            out[f"pipeline/{stage}/occupancy"] = st.busy_s / wall
+            busy = self._span_s.get(f"stage/{stage}", 0.0)
+            out[f"pipeline/{stage}/busy_s"] = busy
+            out[f"pipeline/{stage}/occupancy"] = busy / wall
             out[f"pipeline/{stage}/items"] = float(st.items)
             if stage != "loader":        # stages fed by a bounded queue
                 avg = (st.depth_sum / st.depth_samples
                        if st.depth_samples else 0.0)
                 out[f"pipeline/{stage}/queue_depth"] = avg
                 out[f"pipeline/{stage}/queue_depth_max"] = float(st.depth_max)
+        for p in PREPARE_PHASES:
+            out[f"pipeline/prepare/{p}_s"] = self._span_s.get(
+                f"prepare/{p}", 0.0)
+        out["pipeline/put/lost_rows"] = (
+            0.0 if self._lost_rows is None else float(self._lost_rows))
         for n, v in self.max_outstanding.items():
             out[f"pipeline/outstanding_puts_max/{n}"] = float(v)
         return out
