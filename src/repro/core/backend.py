@@ -107,6 +107,20 @@ def _fault_apply_acc(table, slot_ids, acc, vslots, vecs, ids, accs):
                 acc.at[vslots].set(accs))
 
 
+def _lru_victims(clock: np.ndarray, cand_slots: np.ndarray,
+                 n: int) -> np.ndarray:
+    """The ``n`` least-recently-touched of ``cand_slots`` (ascending slot
+    indices into ``clock``), oldest first, ties broken by slot index:
+    exactly ``cand_slots[np.argsort(clock[cand_slots], kind="stable")[:n]]``
+    in linear time. ``clock * len(clock) + slot`` is unique per slot and
+    orders like (clock, slot), so a partition finds the ``n`` smallest and
+    only those are sorted; the slot is the key's remainder."""
+    size = clock.size
+    key = np.partition(clock[cand_slots] * size + cand_slots, n - 1)[:n]
+    key.sort()
+    return key % size
+
+
 @jax.jit
 def _gather_rows(table, eslots):
     return table[eslots].astype(jnp.float32)
@@ -581,11 +595,9 @@ class HostLRUBackend(EmbeddingBackend):
         self.dev_slots = self.cache_rows + self.bypass_rows
         self.store: LRUEmbeddingStore | TieredHostStore | None = None
         self._lock = threading.RLock()
-        self._slot_for_id: dict[int, int] = {}
-        # vectorized mirror of _slot_for_id (id -> cache slot, -1 = absent):
-        # the per-step id->slot translation is a numpy gather instead of a
-        # per-id dict sweep — the dict stays authoritative for the sparse
-        # mutations (fault-in adds, eviction deletes) and introspection
+        # the slot map lives on two arrays only, kept inverse to each
+        # other: id -> cache slot (-1 = absent) and slot -> id (-1 = empty);
+        # fault-ins and evictions update both with one scatter each
         self._slot_arr = np.full(spec.rows, -1, np.int32)
         self._id_for_slot = np.full(self.dev_slots, -1, np.int64)
         self._slot_clock = np.zeros(self.dev_slots, np.int64)
@@ -654,7 +666,6 @@ class HostLRUBackend(EmbeddingBackend):
         self.store.preload(np.asarray(ids, np.int64),
                            np.asarray(vecs, np.float32), accs)
         # a (re-)init starts a fresh run: drop any previous slot bookkeeping
-        self._slot_for_id = {}
         self._slot_arr = np.full(spec.rows, -1, np.int32)
         self._id_for_slot = np.full(self.dev_slots, -1, np.int64)
         self._slot_clock = np.zeros(self.dev_slots, np.int64)
@@ -768,9 +779,6 @@ class HostLRUBackend(EmbeddingBackend):
             with span("prepare/fault_h2d", t, rows=m):
                 self._fault_rows(state, victims, missing, vecs, accs)
             with span("prepare/slots", t):
-                smap = self._slot_for_id
-                for k, s in zip(missing.tolist(), victims.tolist()):
-                    smap[k] = s
                 self._slot_arr[missing] = victims
                 self._id_for_slot[victims] = missing
                 touched = np.concatenate([hit_slots, victims])
@@ -859,8 +867,7 @@ class HostLRUBackend(EmbeddingBackend):
                     f"exceeds the device cache ({hi - lo} slots in "
                     f"[{lo}, {hi}), {int(pinned.sum())} pinned) — lower "
                     "max_inflight or raise EmbeddingSpec.cache_rows")
-            order = np.argsort(self._slot_clock[cand_slots], kind="stable")
-            evict = cand_slots[order[:n_evict]]
+            evict = _lru_victims(self._slot_clock, cand_slots, n_evict)
         self._evict_slots(evict, state)
         return np.concatenate([free, evict])
 
@@ -887,8 +894,6 @@ class HostLRUBackend(EmbeddingBackend):
             self.store.write_rows(ev_ids, vecs, accs)
         self.writebacks += n_evict
         with span("prepare/slots", t):
-            for k in ev_ids.tolist():
-                del self._slot_for_id[k]
             self._slot_arr[ev_ids] = -1
             self._id_for_slot[evict] = -1
 
@@ -899,19 +904,23 @@ class HostLRUBackend(EmbeddingBackend):
     # recycled them would make the pending lookup read the WRONG row (not a
     # stale one) and silently drop the put. Pins are reference counts; a
     # fault-in that cannot find enough unpinned victims raises (the
-    # combined in-flight working set must fit the cache).
+    # combined in-flight working set must fit the cache). A batch's slots
+    # repeat, so counts are added as one bincount over the slot pool.
+
+    def _slot_counts(self, dev_ids) -> np.ndarray:
+        slots = np.asarray(dev_ids, np.int64).reshape(-1)
+        slots = slots[(slots >= 0) & (slots < self.dev_slots)]
+        return np.bincount(slots, minlength=self.dev_slots)
 
     def pin_slots(self, dev_ids):
-        slots = np.asarray(dev_ids, np.int64).reshape(-1)
-        slots = slots[(slots >= 0) & (slots < self.dev_slots)]
+        counts = self._slot_counts(dev_ids)
         with self._lock:
-            np.add.at(self._pin_count, slots, 1)
+            self._pin_count += counts
 
     def unpin_slots(self, dev_ids):
-        slots = np.asarray(dev_ids, np.int64).reshape(-1)
-        slots = slots[(slots >= 0) & (slots < self.dev_slots)]
+        counts = self._slot_counts(dev_ids)
         with self._lock:
-            np.subtract.at(self._pin_count, slots, 1)
+            self._pin_count -= counts
             np.maximum(self._pin_count, 0, out=self._pin_count)
 
     def reset_pins(self):
@@ -959,7 +968,7 @@ class HostLRUBackend(EmbeddingBackend):
                     .astype(jnp.float32))
             else:
                 m_vecs = np.zeros((0, spec.dim), np.float32)
-            np.add.at(self._pin_count, hit_slots, 1)
+            self._pin_count += self._slot_counts(hit_slots)
         try:
             if hit_slots.size:
                 idx = np.zeros(_pow2_bucket(hit_slots.size), np.int64)
@@ -1242,15 +1251,19 @@ class HostLRUBackend(EmbeddingBackend):
         if self._sketch is not None:
             self._sketch = (HotnessSketch.deserialize(cm["hotness"])
                             if "hotness" in cm else HotnessSketch())
-        self._slot_for_id = {
-            int(k): int(s)
-            for s, k in enumerate(self._id_for_slot.tolist()) if k >= 0}
         self._slot_arr = np.full(spec.rows, -1, np.int32)
         live = np.nonzero(self._id_for_slot >= 0)[0]
         self._slot_arr[self._id_for_slot[live]] = live.astype(np.int32)
         return {k: jnp.asarray(v) for k, v in blob["cache"].items()}
 
     # -- capacity accounting / inspection ------------------------------------
+
+    def slot_map(self) -> dict[int, int]:
+        """``{id: cache slot}`` of the resident rows, read off the slot ->
+        id array (for inspection; the fault path reads the arrays)."""
+        with self._lock:
+            live = np.nonzero(self._id_for_slot >= 0)[0]
+            return dict(zip(self._id_for_slot[live].tolist(), live.tolist()))
 
     def host_bytes(self) -> int:
         s = self.store

@@ -11,8 +11,9 @@ import pytest
 
 from repro.configs.base import ModelConfig
 from repro.core import adapters, embedding_ps as PS
+import repro.core.backend as BK
 from repro.core.backend import (CompressedWireBackend, DenseBackend,
-                                HostLRUBackend, create_backend,
+                                HostLRUBackend, _lru_victims, create_backend,
                                 parse_backend_name)
 from repro.core.collection import EmbeddingCollection
 from repro.core.embedding_ps import EmbeddingSpec
@@ -245,7 +246,8 @@ def test_host_lru_stale_put_to_recycled_slot_is_dropped():
     state, queue, _ = bk.hybrid_update(state, queue, dev, g)   # queued put(0)
     # fault ids 1,2 into the 2-slot cache: id 0 must get evicted
     state, dev12 = bk.prepare(state, np.array([1, 2]))
-    assert 0 not in bk._slot_for_id
+    assert 0 not in bk.slot_map()
+    assert int(bk._slot_arr[0]) == -1
     before = np.asarray(state["table"]).copy()
     zero = jnp.zeros((2, 2))
     # the pop of put(0) happens here; its slot now belongs to id 1 or 2
@@ -394,3 +396,141 @@ def test_compressed_queue_holds_deduped_puts():
     np.testing.assert_allclose(qg[3], 2 * np.ones(4), rtol=1e-3)
     np.testing.assert_allclose(qg[5], 3 * np.ones(4), rtol=1e-3)
     assert float(m["put_bytes_wire"]) < float(m["put_bytes_raw"])
+
+
+# ---------------------------------------------------------------------------
+# host_lru slot bookkeeping on arrays: victim choice and pin counts are
+# exactly the rules they replace (the old rules kept here as oracles)
+# ---------------------------------------------------------------------------
+
+def _argsort_victims(clock, cand_slots, n):
+    return cand_slots[np.argsort(clock[cand_slots], kind="stable")[:n]]
+
+
+@pytest.mark.parametrize("ties", [2, 7, 10**6])
+@pytest.mark.parametrize("n", ["one", "some", "all"])
+def test_lru_victims_is_the_stable_argsort(ties, n):
+    rng = np.random.default_rng(ties)
+    clock = rng.integers(0, ties, 5000).astype(np.int64)
+    cand = np.nonzero(rng.random(5000) < 0.7)[0]
+    k = {"one": 1, "some": cand.size // 3, "all": cand.size}[n]
+    np.testing.assert_array_equal(_lru_victims(clock, cand, k),
+                                  _argsort_victims(clock, cand, k))
+
+
+@pytest.mark.parametrize("region", ["pool", "main", "bypass"])
+@pytest.mark.parametrize("n_evict", ["one", "some", "all"])
+def test_free_slots_victims_match_stable_argsort(region, n_evict):
+    """_free_slots takes the region's empty unpinned slots first, then the
+    oldest evictable ones in the stable argsort's order: clocks full of
+    ties, protected (batch-hit) and pinned slots, both admission regions."""
+    spec = EmbeddingSpec(rows=512, dim=4, mode="full", optimizer="adagrad",
+                         backend="host_lru", cache_rows=96,
+                         admit_threshold=2.0, bypass_rows=32)
+    bk = create_backend(spec)
+    state = bk.init(jax.random.PRNGKey(0))
+    n = bk.dev_slots
+    rng = np.random.default_rng(["pool", "main", "bypass"].index(region))
+    occ = np.nonzero(rng.random(n) < 0.85)[0]
+    ids = rng.choice(spec.rows, occ.size, replace=False)
+    bk._id_for_slot[occ] = ids
+    bk._slot_arr[ids] = occ
+    bk._slot_clock[:] = rng.integers(0, 5, n)
+    bk._pin_count[:] = np.where(rng.random(n) < 0.1,
+                                rng.integers(1, 4, n), 0)
+    protected = rng.choice(occ, occ.size // 6, replace=False)
+    lo, hi = {"pool": (0, n), "main": (0, bk.cache_rows),
+              "bypass": (bk.cache_rows, n)}[region]
+    in_region = np.zeros(n, bool)
+    in_region[lo:hi] = True
+    unpinned = bk._pin_count == 0
+    free = np.nonzero((bk._id_for_slot < 0) & unpinned & in_region)[0]
+    cand = in_region & unpinned & (bk._id_for_slot >= 0)
+    cand[protected] = False
+    cand = np.nonzero(cand)[0]
+    k = {"one": 1, "some": cand.size // 2, "all": cand.size}[n_evict]
+    want = np.concatenate([free, _argsort_victims(bk._slot_clock, cand, k)])
+    ev_ids = bk._id_for_slot[want[free.size:]].copy()
+    got = bk._free_slots(protected, free.size + k, dict(state), lo=lo,
+                         hi=None if region == "pool" else hi)
+    np.testing.assert_array_equal(got, want)
+    assert bk.writebacks == k
+    assert (bk._id_for_slot[want] == -1).all()
+    assert (bk._slot_arr[ev_ids] == -1).all()
+
+
+def test_bincount_pins_match_add_at():
+    spec = EmbeddingSpec(rows=256, dim=4, mode="full", optimizer="sgd",
+                         backend="host_lru", cache_rows=64)
+    bk = create_backend(spec)
+    state = bk.init(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    want = np.zeros(bk.dev_slots, np.int32)
+    batches = [rng.integers(-3, 70, 300) for _ in range(4)]   # repeats, pads
+    for b in batches:
+        bk.pin_slots(jnp.asarray(b, jnp.int32))
+        np.add.at(want, b[(b >= 0) & (b < bk.dev_slots)], 1)
+    np.testing.assert_array_equal(bk._pin_count, want)
+    for b in batches[:3] + [rng.integers(0, 64, 500)]:   # the last over-unpins
+        bk.unpin_slots(b)
+        np.subtract.at(want, b[(b >= 0) & (b < bk.dev_slots)], 1)
+        np.maximum(want, 0, out=want)
+        np.testing.assert_array_equal(bk._pin_count, want)
+    # the serve path pins its hit slots across its gather, then releases
+    state, _ = bk.prepare(state, np.array([3, 3, 9, 40]))
+    bk.pin_slots(np.array([5, 5, 7]))
+    before = bk._pin_count.copy()
+    bk.read_rows(state, np.array([[3, 9], [9, 200]]))
+    np.testing.assert_array_equal(bk._pin_count, before)
+
+
+def _zipf_ids(rng, rows, n, a=1.2):
+    u = rng.random(n)
+    r = ((rows ** (1 - a) - 1) * u + 1) ** (1 / (1 - a))
+    return np.minimum(np.floor(r).astype(np.int64) - 1, rows - 1)
+
+
+def _run_host_lru_zipf(steps=40):
+    spec = EmbeddingSpec(rows=4096, dim=8, mode="full", optimizer="adagrad",
+                         lr=0.1, staleness=1, backend="host_lru",
+                         cache_rows=384)
+    bk = create_backend(spec)
+    state = bk.init(jax.random.PRNGKey(0))
+    queue = bk.queue_init((256,))
+    rng = np.random.default_rng(7)
+    inflight, evictions = None, []
+    for _ in range(steps):
+        ids = _zipf_ids(rng, spec.rows, 256)
+        wb = bk.writebacks
+        state, dev = bk.prepare(state, ids)
+        evictions.append(bk.writebacks - wb)
+        bk.pin_slots(dev)
+        if inflight is not None:
+            bk.unpin_slots(inflight)
+        inflight = dev
+        g = jnp.asarray(rng.standard_normal((256, spec.dim)), jnp.float32)
+        state, queue, _ = bk.hybrid_update(state, queue, dev, g)
+    return bk, state, evictions
+
+
+def test_host_lru_array_bookkeeping_bit_exact_with_argsort_rule(monkeypatch):
+    """A Zipf stream that evicts every step once the cache is full, run
+    with the linear-time victim choice and again with the stable argsort:
+    slot maps, clocks, counters, device cache and host store all equal."""
+    bk, state, evictions = _run_host_lru_zipf()
+    with monkeypatch.context() as m:
+        m.setattr(BK, "_lru_victims", _argsort_victims)
+        ref, ref_state, _ = _run_host_lru_zipf()
+    assert min(evictions[5:]) > 0, evictions
+    for a in ("_slot_arr", "_id_for_slot", "_slot_clock", "_pin_count"):
+        np.testing.assert_array_equal(getattr(bk, a), getattr(ref, a),
+                                      err_msg=a)
+    assert (bk.faults, bk.writebacks, bk.hits) == \
+        (ref.faults, ref.writebacks, ref.hits)
+    assert bk.slot_map() == ref.slot_map()
+    for k in state:
+        np.testing.assert_array_equal(np.asarray(state[k]),
+                                      np.asarray(ref_state[k]), err_msg=k)
+    sa, sb = bk.store.serialize(), ref.store.serialize()
+    for k in sa:
+        np.testing.assert_array_equal(sa[k], sb[k], err_msg=k)

@@ -358,7 +358,8 @@ def test_host_lru_prepare_consumes_plan_uniques():
     sa, dev_a = a.prepare(sa, ids)
     uniq = np.unique(ids[ids >= 0])
     sb, dev_b = b.prepare(sb, uniq, assume_unique=True)
-    assert a._slot_for_id == b._slot_for_id
+    assert a.slot_map() == b.slot_map()
+    np.testing.assert_array_equal(a._slot_arr, b._slot_arr)
     assert a.faults == b.faults == 3
 
 

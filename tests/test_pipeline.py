@@ -254,8 +254,10 @@ def test_host_lru_pinned_slots_survive_fault_in():
     state, dev1 = bk.prepare(state, np.array([10, 11]))
     assert not set(np.asarray(dev1).tolist()) & \
         set(np.asarray(dev0).tolist())
+    smap = bk.slot_map()
     for i in range(6):                          # batch 0 still resident
-        assert bk._slot_for_id[i] == int(np.asarray(dev0)[i])
+        assert smap[i] == int(np.asarray(dev0)[i])
+        assert int(bk._slot_arr[i]) == int(np.asarray(dev0)[i])
     # ... but a batch needing more than the unpinned residue must raise,
     # not silently recycle pinned rows (batch 1's slots are unpinned, so 2
     # are evictable; 3 disjoint ids need one pinned victim -> refused)
@@ -323,8 +325,11 @@ def test_host_lru_prepare_is_thread_safe():
         t.join(timeout=60)
     assert not errors, errors
     # bijection: id->slot and slot->id agree, no slot serves two ids
-    assert len(set(bk._slot_for_id.values())) == len(bk._slot_for_id)
-    for k, s in bk._slot_for_id.items():
+    smap = bk.slot_map()
+    assert len(set(smap.values())) == len(smap)
+    for k, s in smap.items():
         assert int(bk._id_for_slot[s]) == k
+        assert int(bk._slot_arr[k]) == s
     occupied = {int(s) for s in np.nonzero(bk._id_for_slot >= 0)[0]}
-    assert occupied == set(bk._slot_for_id.values())
+    assert occupied == set(smap.values())
+    assert int(np.count_nonzero(bk._slot_arr >= 0)) == len(smap)

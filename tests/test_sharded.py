@@ -269,11 +269,14 @@ def test_sharded_prepare_is_thread_safe():
         t.join(timeout=60)
     assert not errors, errors
     for s, sub in enumerate(bk.shard_backends):
-        assert len(set(sub._slot_for_id.values())) == len(sub._slot_for_id)
-        for k, slot in sub._slot_for_id.items():
+        smap = sub.slot_map()
+        assert len(set(smap.values())) == len(smap)
+        for k, slot in smap.items():
             assert int(sub._id_for_slot[slot]) == k, (s, k)
+            assert int(sub._slot_arr[k]) == slot, (s, k)
         occupied = {int(x) for x in np.nonzero(sub._id_for_slot >= 0)[0]}
-        assert occupied == set(sub._slot_for_id.values())
+        assert occupied == set(smap.values())
+        assert int(np.count_nonzero(sub._slot_arr >= 0)) == len(smap)
 
 
 # ---------------------------------------------------------------------------
