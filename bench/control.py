@@ -14,6 +14,9 @@ the program's place, one at a time:
 * ``half_batch``: the reference with the loss averaged over the first half
   of each batch only.
 
+These readings run the reference alone, at the cell's global batch, on one
+chip.
+
 With ``--fault <name>``, a fault from ``FAULTS`` is planted in the program
 and a whole run (a window of ``--seconds``) is made per seed. Each prints
 one JSON line per seed and case with the three compared numbers, the
@@ -72,21 +75,32 @@ def drop_writeback(patch):
     patch(lru.LRUEmbeddingStore, "write_rows", write_rows)
 
 
+def no_exchange(patch):
+    """The sharded tables' lookup leaves out the exchange between chips:
+    each chip keeps the partial rows of the ids it owns, and the rows the
+    other chips own read as zeros."""
+    import jax
+
+    def psum(x, axis_name, **kw):
+        return x
+
+    patch(jax.lax, "psum", psum)
+
+
 FAULTS = {"unchanged_state": unchanged_state, "half_batch": half_batch,
-          "drop_writeback": drop_writeback}
+          "drop_writeback": drop_writeback, "no_exchange": no_exchange}
 
 
 def readings(cell, seed: int) -> list:
     import jax.numpy as jnp
     from bench.harness import compare, reference
     cfg = cell.config
-    rows = int(cfg["rows_per_field"])
     batches = cell.batches(seed, 0, int(cfg["check"]["steps"]))
-    ref = reference.Reference(cfg, seed, rows).run(batches)
+    ref = reference.Reference(cfg, seed, cell.tower).run(batches)
     out = []
     for case, kw in (("control", {"dtype": jnp.bfloat16}),
                      ("half_batch", {"half_batch": True})):
-        got = reference.Reference(cfg, seed, rows, **kw).run(batches)
+        got = reference.Reference(cfg, seed, cell.tower, **kw).run(batches)
         g = compare.gaps(got, ref)
         out.append({"seed": seed, "case": case,
                     **{n: g[n] for n in compare.NAMES},
@@ -117,7 +131,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     from bench.harness import device, spec
     cell = spec.resolve(args.workload)
-    devices = device.require_tpu(cell.chips)
+    devices = device.require_tpu(cell.chips if args.fault else 1)
     if args.fault:
         from bench import run       # puts the program on the path
         run.enable_compile_cache()

@@ -11,8 +11,15 @@ A configuration names one of two paths:
 
 Both are timed from the host. A watcher thread waits on each step's loss in
 order and takes the clock when it is ready, so completions are seen without
-the loop forcing a sync per step. One chip: a cell on a mesh needs the
-batch laid out over it and the tables sharded, which no cell asks for yet.
+the loop forcing a sync per step.
+
+On several chips (``fused`` only) the trainer is built, initialised,
+stepped and read under a one-axis mesh ``("data",)`` over them: each batch
+is laid out over the chips on its batch axis, and the tables as their
+specs' ``mode`` lays them out (``'full'``: rows sharded over every chip).
+
+Each field gets its own table spec: its rows, its backend and its cache,
+as ``spec.fields`` reads them from the configuration.
 """
 from __future__ import annotations
 
@@ -25,10 +32,13 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from bench.harness import spec
 from repro.configs.base import ModelConfig
 from repro.core import adapters
 from repro.core import backend as BK
+from repro.core.collection import EmbeddingCollection
 from repro.core.hybrid import PersiaTrainer, TrainMode
 from repro.core.pipeline import PipelinedTrainer
 from repro.launch.shards import apply_backend_choice
@@ -46,66 +56,113 @@ class Window:
 
 
 class System:
-    """One trainer built from a configuration, with its state."""
+    """One trainer built from a configuration, with its state. ``batch``
+    is the global batch; ``devices``, where more than one, are the chips of
+    the mesh the trainer runs on."""
 
-    def __init__(self, config: dict, batch: int):
+    def __init__(self, config: dict, batch: int, devices=()):
         self.config = config
         self.batch = batch
-        m = config["model"]
+        self.path = config["trainer"]["path"]
+        self.mesh = None
+        if len(devices) > 1:
+            if self.path != "fused":
+                raise ValueError(f"{config['name']}: the {self.path!r} path "
+                                 "runs on one chip only")
+            self.mesh = jax.make_mesh(
+                (len(devices),), ("data",), devices=list(devices),
+                axis_types=(jax.sharding.AxisType.Auto,))
         t = config["tables"]
-        rows = int(config["rows_per_field"])
+        self.fields = spec.fields(config)
+        model = {k: tuple(v) if isinstance(v, list) else v
+                 for k, v in config["model"].items()}
         cfg = ModelConfig(
             name=config["name"], arch_type="recsys",
-            n_id_fields=m["n_id_fields"], ids_per_field=m["ids_per_field"],
-            emb_dim=m["emb_dim"], emb_rows=rows * m["n_id_fields"],
-            n_dense_features=m["n_dense_features"],
-            mlp_dims=tuple(m["mlp_dims"]), n_tasks=m["n_tasks"],
-            emb_staleness=t["staleness"], emb_optimizer=t["optimizer"])
-        field_rows = (rows,) * m["n_id_fields"]
-        coll = adapters.ctr_collection(cfg, lr=t["lr"],
-                                            field_rows=field_rows)
-        if t["backend"] != "dense":
-            coll = apply_backend_choice(coll, t["backend"],
-                                             int(config["cache_rows"]))
-        adapter = adapters.recsys_adapter(cfg, field_rows=field_rows,
-                                               collection=coll)
+            emb_rows=sum(f["rows"] for f in self.fields),
+            emb_staleness=t["staleness"], emb_optimizer=t["optimizer"],
+            **model)
+        field_rows = tuple(f["rows"] for f in self.fields)
         tw = config["tower"]
         opt = OptConfig(kind=tw["optimizer"], lr=tw["lr"], b1=tw["b1"],
-                             b2=tw["b2"], eps=tw["eps"],
-                             grad_clip=tw["grad_clip"])
-        self.observer = None
-        trainer_cls = _observed(PersiaTrainer, self)
-        self.trainer = trainer_cls(adapter, TrainMode.hybrid(
-            t["staleness"]), opt)
+                        b2=tw["b2"], eps=tw["eps"],
+                        grad_clip=tw["grad_clip"])
+        with self.on_mesh():
+            coll = adapters.ctr_collection(cfg, lr=t["lr"],
+                                           field_rows=field_rows)
+            coll = EmbeddingCollection(tuple(
+                (n, _placed(n, s, f))
+                for (n, s), f in zip(coll.items(), self.fields)))
+            adapter = adapters.recsys_adapter(cfg, field_rows=field_rows,
+                                              collection=coll)
+            self.observer = None
+            trainer_cls = _observed(PersiaTrainer, self)
+            self.trainer = trainer_cls(adapter, TrainMode.hybrid(
+                t["staleness"]), opt)
         self.names = list(coll.names)
-        self.path = config["trainer"]["path"]
+        self._lookup = None
         self.engine = None
         if self.path == "pipelined":
             self.engine = PipelinedTrainer(
                 self.trainer, max_inflight=config["trainer"]["max_inflight"])
         self.state = None
 
+    def on_mesh(self):
+        """The context the trainer runs in: its mesh, where it has one."""
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return jax.sharding.set_mesh(self.mesh)
+
+    def feed(self, batch: dict) -> dict:
+        """A host batch as the step takes it: on a mesh, laid out over the
+        chips on its batch axis; on one chip, as it is."""
+        if self.mesh is None:
+            return batch
+        return jax.device_put(batch, NamedSharding(self.mesh, P("data")))
+
     def init(self, seed: int, example: dict):
-        """Draw the model from ``seed``. Dense tables are drawn on the
-        device in one jitted call (a traced key takes the table draw onto
-        the device); host-backed tables are drawn into the host store, as
-        their backend does."""
+        """Draw the model from ``seed``. On one chip where every table is
+        dense, the draw is one jitted call on the device (a traced key takes
+        the table draw there). Otherwise the program draws as its backends
+        do: host-backed tables into their host store, and on a mesh dense
+        tables on the host, each chip then receiving its own rows (the
+        program lays a table out by ``device_put``, which inside a jitted
+        call does not constrain: a jitted draw would leave every table
+        whole on every chip)."""
         key = jax.random.PRNGKey(seed)
-        if self.config["tables"]["backend"] == "dense":
-            self.state = jax.jit(self.trainer.init)(key, example)
-        else:
-            self.state = self.trainer.init(key, example)
-        jax.block_until_ready(self.state)
+        with self.on_mesh():
+            if self.mesh is None and all(f["backend"] == "dense"
+                                         for f in self.fields):
+                self.state = jax.jit(self.trainer.init)(key, example)
+            else:
+                self.state = self.trainer.init(key, example)
+            jax.block_until_ready(self.state)
 
     # -- reading state (set-up only) ------------------------------------------
 
     def rows(self, ids: dict) -> dict:
         """Current rows of the given logical ids, per table, as fp32 host
-        arrays, read through each backend's read path."""
+        arrays: of a device-resident table through its lookup, all such
+        tables in one jitted call (on a mesh an eager lookup of a sharded
+        table retraces its exchange, a second a table); of a host-backed
+        table through its read path, which reads rows its device cache
+        does not hold from its host store."""
+        backends = self.trainer.backends
+        dev = [n for n in ids if not backends[n].requires_prepare]
         out = {}
-        for n, x in ids.items():
-            r, _ = self.trainer.backends[n].read_rows(self.state.emb[n], x)
-            out[n] = np.asarray(r, np.float64)
+        with self.on_mesh():
+            if dev:
+                if self._lookup is None:
+                    self._lookup = jax.jit(lambda states, xs: {
+                        n: backends[n].lookup(states[n], x)[0]
+                        for n, x in xs.items()})
+                got = self._lookup(
+                    {n: self.state.emb[n] for n in dev},
+                    {n: jnp.asarray(ids[n], jnp.int32) for n in dev})
+                out.update({n: np.asarray(got[n], np.float64) for n in dev})
+            for n in ids:
+                if n not in out:
+                    r, _ = backends[n].read_rows(self.state.emb[n], ids[n])
+                    out[n] = np.asarray(r, np.float64)
         return out
 
     def stored(self, ids: dict) -> dict:
@@ -149,7 +206,9 @@ class System:
     def step_once(self, batch: dict) -> float:
         """One step through the window's own call, waited for."""
         if self.engine is None:
-            self.state, m = self.trainer.step(self.state, batch)
+            with self.on_mesh():
+                self.state, m = self.trainer.step(self.state,
+                                                  self.feed(batch))
             loss = m["loss"]
         else:
             self.state, ms = self.engine.run(self.state, [batch])
@@ -199,8 +258,9 @@ class System:
             depth = int(self.config["trainer"].get("inflight", 2))
             n = 0
             for b in batches(deadline):
-                with ann("bench:step"):
-                    self.state, m = self.trainer.step(self.state, b)
+                with ann("bench:step"), self.on_mesh():
+                    self.state, m = self.trainer.step(self.state,
+                                                      self.feed(b))
                 watcher.put(m["loss"])
                 n += 1
                 with ann("bench:wait"):
@@ -227,8 +287,17 @@ class System:
         self.state = None
         self.engine = None
         self.trainer = None
+        self._lookup = None
         import gc
         gc.collect()
+
+
+def _placed(name: str, table, field: dict):
+    """``table``'s spec with the field's backend and device cache, set as
+    the program's launchers set a backend choice."""
+    one = EmbeddingCollection.single(name, table)
+    return apply_backend_choice(one, field["backend"],
+                                field["cache_rows"]).items()[0][1]
 
 
 def _observed(base, system):

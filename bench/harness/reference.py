@@ -1,16 +1,16 @@
 """A plain reference of the hybrid CTR training step, in ``jax.numpy``.
 
 It imports nothing of the program and takes nothing the program made. From
-the seed it draws the same model the configuration describes (tower weights
-N(0, 2/fan_in), zero biases; table rows N(0, scale^2), logical id ``i`` of a
-table of ``R`` rows holding draw row ``(i * 1000003 + 12345) mod R``, the
-uniform shuffle of paper section 4.2.3) and follows the first training steps
-on the same batches:
+the seed it draws the same model the configuration describes (the tower as
+its module under ``bench/towers/`` draws it; table rows N(0, scale^2),
+logical id ``i`` of a field's table of ``R`` rows holding draw row
+``(i * 1000003 + 12345) mod R``, the uniform shuffle of paper section
+4.2.3) and follows the first training steps on the same batches:
 
 * lookup: each field's bag of rows, summed over its valid ids;
 * tower: the pooled rows and the dense features, concatenated in field
-  order, through ReLU layers to one logit per task; binary cross-entropy
-  with logits, averaged over samples and tasks;
+  order, through the tower module's ``forward`` to one logit per task;
+  binary cross-entropy with logits, averaged over samples and tasks;
 * tower update: gradients clipped to a global norm, then Adam;
 * table update (Persia Alg. 1 with staleness tau): each step's per-row sum
   of occurrence gradients joins a FIFO; the put pushed tau steps earlier
@@ -27,11 +27,13 @@ from __future__ import annotations
 
 import collections
 import contextlib
-import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench.harness import spec
 
 SHUFFLE_MULT = 1_000_003
 SHUFFLE_ADD = 12_345
@@ -49,7 +51,8 @@ def touched(batches: list, n_fields: int) -> list:
     """Sorted distinct valid ids per field over ``batches``."""
     out = []
     for f in range(n_fields):
-        x = np.concatenate([b["ids"][:, f].reshape(-1) for b in batches])
+        x = np.concatenate([np.asarray(spec.field_ids(b["ids"], f))
+                            .reshape(-1) for b in batches])
         out.append(np.unique(x[x >= 0]).astype(np.int64))
     return out
 
@@ -65,11 +68,15 @@ def padded(ids_per_field: list) -> list:
 
 
 class Reference:
-    def __init__(self, config: dict, seed: int, rows_per_field: int,
+    """``tower`` is the module of the configuration's tower (``Cell.tower``):
+    its ``init(key, model, d_in)`` and ``forward(params, x, model)``."""
+
+    def __init__(self, config: dict, seed: int, tower,
                  dtype=jnp.float32, half_batch: bool = False):
         self.config = config
         self.seed = seed
-        self.rows = int(rows_per_field)
+        self.rows = [f["rows"] for f in spec.fields(config)]
+        self.tower = tower
         self.dtype = dtype
         self.half_batch = half_batch
 
@@ -77,39 +84,30 @@ class Reference:
 
     def _init(self, ids_per_field: list):
         m, t = self.config["model"], self.config["tables"]
-        d_in = m["n_id_fields"] * m["emb_dim"] + m["n_dense_features"]
-        dims = [d_in, *m["mlp_dims"], m["n_tasks"]]
         kd, ke = jax.random.split(jax.random.PRNGKey(self.seed))
-        ks = jax.random.split(kd, len(dims))
-        tower = {"mlp": [
-            {"b": jnp.zeros((dims[i + 1],), jnp.float32),
-             "w": jax.random.normal(ks[i], (dims[i], dims[i + 1]),
-                                    jnp.float32)
-             * math.sqrt(2.0 / dims[i])}
-            for i in range(len(dims) - 1)]}
+        tower = self.tower.init(kd, m, spec.tower_input(self.config))
         keys = jax.random.split(ke, m["n_id_fields"])
-        draw = jax.jit(lambda k, idx: (jax.random.normal(
-            k, (self.rows, m["emb_dim"]), jnp.float32)
-            * t["init_scale"])[idx])
-        tables = [draw(keys[f], jnp.asarray(shuffle(ids, self.rows),
-                                            jnp.int32))
+
+        @partial(jax.jit, static_argnums=2)
+        def draw(k, idx, rows):
+            return (jax.random.normal(k, (rows, m["emb_dim"]), jnp.float32)
+                    * t["init_scale"])[idx]
+
+        tables = [draw(keys[f], jnp.asarray(shuffle(ids, self.rows[f]),
+                                            jnp.int32), self.rows[f])
                   for f, ids in enumerate(padded(ids_per_field))]
         return tower, tables
 
     def _loss(self, tower, tables, cids, dense, labels):
         pooled = []
         for f, e in enumerate(tables):
-            c = cids[:, f]                                   # (B, L)
+            c = spec.field_ids(cids, f)                      # (B, L_f)
             rows = e[jnp.where(c >= 0, c, 0)]
             pooled.append(jnp.sum(rows * (c >= 0)[..., None].astype(e.dtype),
                                   axis=1))
         x = jnp.concatenate(pooled + [dense.astype(self.dtype)], axis=-1)
-        n = len(tower["mlp"])
-        for i, lyr in enumerate(tower["mlp"]):
-            x = x @ lyr["w"] + lyr["b"]
-            if i < n - 1:
-                x = jax.nn.relu(x)
-        z, y = x, labels.astype(self.dtype)
+        z = self.tower.forward(tower, x, self.config["model"])
+        y = labels.astype(self.dtype)
         nll = jnp.maximum(z, 0) - z * y + jnp.log1p(jnp.exp(-jnp.abs(z)))
         if self.half_batch:
             nll = nll[: nll.shape[0] // 2]
@@ -179,10 +177,9 @@ class Reference:
             fifo = collections.deque()
             losses, grad1 = [], {}
             for k, b in enumerate(batches):
-                cids = np.stack([_compact(b["ids"][:, f], ids[f])
-                                 for f in range(F)], axis=1)
+                cids = _compact_all(b["ids"], ids)
                 loss, tower, opt, g_t, g_e = step(
-                    tower, opt, tables, jnp.asarray(cids),
+                    tower, opt, tables, cids,
                     jnp.asarray(b["dense"]), jnp.asarray(b["labels"]))
                 losses.append(float(loss))
                 if k == 0:
@@ -217,6 +214,16 @@ def _compact(ids: np.ndarray, uniq: np.ndarray) -> np.ndarray:
     """Logical ids -> positions in the sorted touched set (-1 kept)."""
     pos = np.searchsorted(uniq, np.where(ids >= 0, ids, 0))
     return np.where(ids >= 0, pos, -1).astype(np.int32)
+
+
+def _compact_all(ids, uniq: list):
+    """A batch's ``ids`` compacted field by field, in the batch's own
+    layout: one ``(B, F, L)`` array, or a list of ``(B, L_f)`` arrays."""
+    per = [_compact(np.asarray(spec.field_ids(ids, f)), u)
+           for f, u in enumerate(uniq)]
+    if isinstance(ids, (list, tuple)):
+        return [jnp.asarray(c) for c in per]
+    return jnp.asarray(np.stack(per, axis=1))
 
 
 def _tower_leaves(tree) -> dict:

@@ -1,39 +1,25 @@
 """The work a training step must do, counted from shapes.
 
 These are the algorithm's operations and bytes, not what a compiled program
-happens to execute, so a later change to the program cannot move them.
+happens to execute, so a later change to the program cannot move them. The
+tower's operations are counted by its own module (``bench/towers/``).
 """
 from __future__ import annotations
 
 import numpy as np
 
+from bench.harness import spec
+
 FP32 = 4
 ID = 4
 
 
-def tower_dims(model: dict) -> list[int]:
-    """Widths of the tower from its input to its logits."""
-    d_in = model["n_id_fields"] * model["emb_dim"] + model["n_dense_features"]
-    return [d_in, *model["mlp_dims"], model["n_tasks"]]
-
-
-def tower_flops_per_sample(model: dict) -> float:
-    """Forward and backward FLOPs of the tower for one sample.
-
-    Each layer is one (in x out) matmul: 2*in*out FLOPs forward, and twice
-    that backward (the gradient of the weights and of the layer's input; the
-    first layer's input gradient is the embedding gradient, so it is needed
-    too). Bias, activation and loss are left out."""
-    dims = tower_dims(model)
-    macs = sum(a * b for a, b in zip(dims[:-1], dims[1:]))
-    return 6.0 * macs
-
-
-def unique_counts(ids: np.ndarray) -> np.ndarray:
-    """Distinct valid ids per field of one batch ``(B, F, L)`` (-1 pads)."""
-    out = np.zeros(ids.shape[1], np.int64)
-    for f in range(ids.shape[1]):
-        x = ids[:, f].reshape(-1)
+def unique_counts(ids, n_fields: int) -> np.ndarray:
+    """Distinct valid ids per field of one batch's ``ids`` (-1 pads), in
+    either layout ``spec.field_ids`` reads."""
+    out = np.zeros(n_fields, np.int64)
+    for f in range(n_fields):
+        x = np.asarray(spec.field_ids(ids, f)).reshape(-1)
         out[f] = np.unique(x[x >= 0]).size
     return out
 

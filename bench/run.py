@@ -20,6 +20,10 @@ mix are files under ``bench/`` named there. A run:
 5. frees the program's state and runs the plain reference over the compared
    steps; ``correct`` is the comparison against the configuration's limits.
 
+A cell on several chips runs on a mesh over them (``harness/program.py``);
+its batch is the batch per chip times the chips, and the reference follows
+that global batch on one chip.
+
 The last line of standard output is one JSON object. Without a TPU, or with
 fewer chips than the cell needs, the run exits 2 and prints no result.
 """
@@ -30,7 +34,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -104,9 +107,10 @@ def main(argv=None) -> int:
     except (OSError, KeyError, ValueError) as e:
         print(f"bench: {e}", file=sys.stderr)
         return 2
-    if cell.chips != 1:
-        print(f"bench: {cell.name} asks for {cell.chips} chips; the harness "
-              "runs one-chip cells only", file=sys.stderr)
+    if cell.chips > 1 and cell.config["trainer"]["path"] != "fused":
+        print(f"bench: {cell.name} asks for {cell.chips} chips on the "
+              f"{cell.config['trainer']['path']!r} path; only the fused path "
+              "runs on a mesh", file=sys.stderr)
         return 2
     from bench.harness import device
     devices = device.require_tpu(cell.chips)
@@ -188,20 +192,20 @@ def execute(cell, seed: int, seconds: float, traced_run: bool, devices,
     counter = CompileCounter()
     import jax
     import numpy as np
-    from bench.harness import compare, device, program, reference, trace, work
+    from bench.harness import (compare, device, program, reference, spec,
+                               trace, work)
 
-    cfg, mix = cell.config, cell.traffic
+    cfg = cell.config
     model = cfg["model"]
     F = model["n_id_fields"]
-    rows = int(cfg["rows_per_field"])
-    batch = int(mix["batch_per_chip"])
+    batch = cell.batch
     K = int(cfg["check"]["steps"])
     stream = Stream(cell, seed)
     stream.ensure(K + FIRST_DRAW)
     log(f"{len(stream.batches)} batches of {batch} drawn")
 
     # -- set-up: the model, the compared steps, the warm-up ------------------
-    system = program.System(cfg, batch)
+    system = program.System(cfg, batch, devices)
     system.init(seed, stream[0])
     log("model drawn")
     prog = compared_steps(system, stream.batches[:K], F)
@@ -264,7 +268,7 @@ def execute(cell, seed: int, seconds: float, traced_run: bool, devices,
     system.free()
 
     # -- metrics --------------------------------------------------------------
-    flops = work.tower_flops_per_sample(model)
+    flops = cell.tower.flops_per_sample(model, spec.tower_input(cfg))
     values = {}
     if not traced_run:
         values["train_samples_per_s"] = win.samples / win.seconds
@@ -278,7 +282,7 @@ def execute(cell, seed: int, seconds: float, traced_run: bool, devices,
         for i in traced.consumed:
             for j in (i, i - tau):
                 if j not in uniq:
-                    uniq[j] = work.unique_counts(stream[j]["ids"])
+                    uniq[j] = work.unique_counts(stream[j]["ids"], F)
         emb_b = sum(work.emb_bytes(uniq[i], uniq[i - tau], model["emb_dim"])
                     for i in traced.consumed)
         ctx = types.SimpleNamespace(chips=cell.chips, peaks=peaks,
@@ -288,7 +292,7 @@ def execute(cell, seed: int, seconds: float, traced_run: bool, devices,
                                     emb_bytes_traced=emb_b,
                                     counters=counters)
         for m in cell.per_layer:
-            reader = importlib.import_module(f"bench.metrics.{m['name']}")
+            reader = spec.load("metrics", m["name"])
             v = reader.read(ctx)
             if v is not None:
                 values[m["name"]] = v
@@ -300,8 +304,8 @@ def execute(cell, seed: int, seconds: float, traced_run: bool, devices,
     # -- the reference and the comparison -------------------------------------
     compared = stream.batches[:K]
     del stream
-    ref = reference.Reference(cfg, seed, rows).run(compared,
-                                                   prog["subsets"])
+    ref = reference.Reference(cfg, seed, cell.tower).run(compared,
+                                                         prog["subsets"])
     gaps = compare.gaps(prog, ref)
     log("reference run")
     ok, checks = compare.verdict(gaps, cfg["check"]["limits"])

@@ -31,9 +31,8 @@ def test_reference_variant_fails_the_limits(name, case):
     cell = small_cell(name, batch=512, rows=20000)
     cfg = cell.config
     batches = cell.batches(SEED, 0, cfg["check"]["steps"])
-    rows = cfg["rows_per_field"]
-    ref = reference.Reference(cfg, SEED, rows).run(batches)
-    got = reference.Reference(cfg, SEED, rows, **case).run(batches)
+    ref = reference.Reference(cfg, SEED, cell.tower).run(batches)
+    got = reference.Reference(cfg, SEED, cell.tower, **case).run(batches)
     ok, checks = compare.verdict(compare.gaps(got, ref),
                                  cfg["check"]["limits"])
     assert not ok, checks

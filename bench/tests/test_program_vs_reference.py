@@ -3,9 +3,10 @@ each path a cell drives: the fused dense step, and the pipelined host_lru
 trainer with a cache smaller than the rows its compared steps touch."""
 import jax
 import numpy as np
+import pytest
 
 from bench import run as R
-from bench.harness import compare, program, reference
+from bench.harness import compare, program, reference, work
 
 from .conftest import CPU_LIMITS, CPU_PEAKS, small_cell
 
@@ -44,7 +45,7 @@ def test_host_lru_fault_in_and_write_back_match_reference():
     assert len(stored) == cfg["model"]["n_id_fields"]
     assert min(stored.values()) > 0
     system.free()
-    ref = reference.Reference(cfg, SEED, cfg["rows_per_field"]).run(
+    ref = reference.Reference(cfg, SEED, cell.tower).run(
         batches, prog["subsets"])
     g = compare.gaps(prog, ref)
     assert all(ref["change"][k] > 0 for k in prog["subsets"])
@@ -69,8 +70,90 @@ def test_reference_subset_is_the_rows_change():
     ids = reference.touched(batches, cfg["model"]["n_id_fields"])
     every = ids[1]
     half = every[::2]
-    ref = reference.Reference(cfg, SEED, cfg["rows_per_field"]).run(
+    ref = reference.Reference(cfg, SEED, cell.tower).run(
         batches, {"all": (1, every), "half": (1, half), "none": (1, half[:0])})
     assert np.isclose(ref["change"]["all"], ref["change"]["emb/field_01"])
     assert 0 < ref["change"]["half"] < ref["change"]["all"]
     assert ref["change"]["none"] == 0.0
+
+
+# Read at the harness's commit before per-field tables, the tower by name
+# and the mesh path came in (CPU): what the program and the reference give
+# on a tiny preset of each cell, the three gaps, and the embedding bytes
+# counted over the steps. The harness reads the same to the last digit.
+BEFORE = {
+    "criteo-dense-zipf": {
+        "prog_losses": [0.743215799331665, 0.7137912511825562,
+                        0.7294710278511047, 0.7409405708312988,
+                        0.6928321719169617],
+        "ref_losses": [0.743215799331665, 0.7137912511825562,
+                       0.72947096824646, 0.7409405708312988,
+                       0.6928321719169617],
+        "gaps": [8.170941321855667e-08, 2.954642956069691e-07,
+                 2.5698793918366205e-08],
+        "emb_bytes": 1453976.0},
+    "kwai-hostlru-zipf": {
+        "prog_losses": [0.7224737405776978, 0.7194555401802063,
+                        0.7092657685279846, 0.7092567682266235,
+                        0.6528815627098083, 0.6645671129226685,
+                        0.6977107524871826, 0.6565734148025513,
+                        0.6494482159614563, 0.6461275815963745,
+                        0.6469232439994812, 0.6523492336273193],
+        "ref_losses": [0.722473680973053, 0.7194555401802063,
+                       0.7092657685279846, 0.7092567682266235,
+                       0.6528816223144531, 0.6645671129226685,
+                       0.6977107524871826, 0.6565734148025513,
+                       0.6494482159614563, 0.6461275815963745,
+                       0.6469232439994812, 0.6523492336273193],
+        "gaps": [9.129471980554955e-08, 1.3369953950871704e-07,
+                 9.722597343306518e-09],
+        "emb_bytes": 8849624.0},
+}
+
+
+@pytest.mark.parametrize("name,kw", [
+    ("criteo-dense-zipf", {}),
+    ("kwai-hostlru-zipf", {"rows": 5000, "cache_rows": CACHE_ROWS,
+                           "batch": 64})])
+def test_reads_as_before(name, kw):
+    cell = small_cell(name, **kw)
+    cfg = cell.config
+    K = cfg["check"]["steps"]
+    F = cfg["model"]["n_id_fields"]
+    batches = cell.batches(SEED, 0, K)
+    system = program.System(cfg, cell.batch)
+    system.init(SEED, batches[0])
+    prog = R.compared_steps(system, batches, F)
+    system.free()
+    ref = reference.Reference(cfg, SEED, cell.tower).run(batches,
+                                                         prog["subsets"])
+    g = compare.gaps(prog, ref)
+    u = [work.unique_counts(b["ids"], F) for b in batches]
+    got = {"prog_losses": prog["losses"], "ref_losses": ref["losses"],
+           "gaps": [g[n] for n in compare.NAMES],
+           "emb_bytes": sum(work.emb_bytes(u[i], u[i - 3],
+                                           cfg["model"]["emb_dim"])
+                            for i in range(3, K))}
+    assert got == BEFORE[name]
+
+
+def test_reference_reads_per_field_id_lists():
+    """The reference follows the same steps whether a batch holds its ids
+    as one padded ``(B, F, L)`` array or as a list of per-field
+    ``(B, L_f)`` arrays, each cut to its own widest bag."""
+    cell = small_cell("criteo-dense-zipf", batch=64)
+    cfg = cell.config
+    batches = cell.batches(SEED, 0, 3)
+    widths = [1, 2, 2]
+    ragged = [dict(b, ids=[b["ids"][:, f, :w] for f, w in enumerate(widths)])
+              for b in batches]
+    for b in batches:        # the cut drops only padding
+        b["ids"][:, 0, 1:] = -1
+    padded = reference.Reference(cfg, SEED, cell.tower).run(batches)
+    ragged = reference.Reference(cfg, SEED, cell.tower).run(ragged)
+    assert [len(x) for x in ragged["touched"]] == \
+        [len(x) for x in padded["touched"]]
+    np.testing.assert_allclose(ragged["losses"], padded["losses"],
+                               rtol=1e-6)
+    for k, v in padded["change"].items():
+        np.testing.assert_allclose(ragged["change"][k], v, rtol=1e-5)

@@ -92,6 +92,36 @@ def test_exposed_collective_and_idle():
     assert gaps["no host span"] == pytest.approx(10e-9)  # the tail [30, 40)
 
 
+def test_collective_exposed_ms():
+    """The worst chip's exposed collective time per traced step: TPU:0 as
+    in the test above (10 ns exposed), TPU:1 with its all-gather wholly
+    under compute; over two steps. A trace without collectives reads
+    nothing."""
+    import types
+    from bench.metrics import collective_exposed_ms
+    ar = "%all-reduce.1 = f32[4]{0} all-reduce(f32[4]{0} %x)"
+    ag = "%all-gather.2 = s32[8]{0} all-gather(s32[2]{0} %i)"
+    mm = "%dot.1 = f32[4,4]{1,0} dot(f32[4,4] %a, f32[4,4] %b)"
+    td = T.TraceData(
+        devices={TPU0: {T.OPS_LINE: [(0.0, 10.0, mm), (20.0, 10.0, mm)],
+                        T.ASYNC_LINE: [(5.0, 20.0, ar)]},
+                 "/device:TPU:1": {T.OPS_LINE: [(0.0, 30.0, mm),
+                                                (10.0, 5.0, ag)]}},
+        host=[(0.0, 40.0, T.WINDOW)])
+    r = T.reduce(td, steps=2)
+    assert r["devices"]["/device:TPU:1"]["exposed_collective_s"] == 0.0
+    run = types.SimpleNamespace(trace=r,
+                                traced=types.SimpleNamespace(steps=2))
+    assert collective_exposed_ms.read(run) == pytest.approx(1e3 * 10e-9 / 2)
+    quiet = T.reduce(T.TraceData(
+        devices={TPU0: {T.OPS_LINE: [(0.0, 10.0, mm)]}},
+        host=[(0.0, 10.0, T.WINDOW)]), steps=1)
+    assert collective_exposed_ms.read(types.SimpleNamespace(
+        trace=quiet, traced=types.SimpleNamespace(steps=1))) is None
+    assert collective_exposed_ms.read(types.SimpleNamespace(
+        trace=None, traced=None)) is None
+
+
 def test_interval_arithmetic():
     assert T.union([(3, 5), (0, 2), (1, 4)]) == [[0, 5]]
     assert T.subtract([[0, 10]], [[2, 3], [5, 6]]) == [[0, 2], [3, 5],

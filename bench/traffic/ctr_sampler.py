@@ -2,16 +2,18 @@
 
 A mix is a data file beside this one (``<name>.json``) that sets the batch
 per chip, the id skew and the seed of the labelling truth; the model's
-shape (fields, multi-hot width, dense features, tasks, rows per field)
-comes from the configuration. The arithmetic is a copy of the program's
-synthetic CTR stream (``src/repro/data/ctr.py``: ``PlantedTruth`` and
-``CTRDataset.sampler``), kept here so that a change to the program cannot
-move the traffic it is measured on:
+shape (dense features, tasks, and each field's rows and widest bag, as
+``bench/harness/spec.fields`` reads them) comes from the configuration.
+The arithmetic is a copy of the program's synthetic CTR stream
+(``src/repro/data/ctr.py``: ``PlantedTruth`` and ``CTRDataset.sampler``),
+kept here so that a change to the program cannot move the traffic it is
+measured on:
 
 * ids: bounded Zipf(``zipf_a``) ranks over each field's own rows, drawn by
   the inverse CDF, one id space per field;
 * ragged multi-hot bags: each (sample, field) keeps a length uniform in
-  ``1..ids_per_field``, the tail padded with -1;
+  ``1..hot`` of its field, the tail padded with -1 to the widest field's
+  ``hot`` (``(B, F, L)``, the layout the program takes);
 * dense features: standard normal;
 * labels: Bernoulli draws from a planted logistic truth over hashed ids and
   dense features, keyed to ``truth_seed`` alone, so every stream labels
@@ -28,6 +30,8 @@ import concurrent.futures
 import os
 
 import numpy as np
+
+from bench.harness import spec
 
 
 class PlantedTruth:
@@ -64,20 +68,22 @@ def truth(model: dict, mix: dict) -> PlantedTruth:
                         model["n_dense_features"], model["n_tasks"])
 
 
-def draw(rng, truth: PlantedTruth, model: dict, rows_per_field: int,
-         mix: dict, batch: int) -> dict:
+def draw(rng, truth: PlantedTruth, model: dict, fields: list, mix: dict,
+         batch: int) -> dict:
     """One batch ``{"ids" (B, F, L) int32, "labels" (B, T) float32,
-    "dense" (B, n_dense) float32}`` from ``rng``."""
-    n_fields = model["n_id_fields"]
-    width = model["ids_per_field"]
+    "dense" (B, n_dense) float32}`` from ``rng``; ``fields`` as
+    ``spec.fields`` gives them."""
+    n_fields = len(fields)
+    rows = _per_field([f["rows"] for f in fields], (1, n_fields, 1))
+    hot = _per_field([f["hot"] for f in fields], (1, n_fields))
+    width = max(f["hot"] for f in fields)
     n_dense = model["n_dense_features"]
     n_tasks = model["n_tasks"]
     a = float(mix["zipf_a"])
     u = rng.random((batch, n_fields, width))
-    ranks = np.floor(((rows_per_field ** (1 - a) - 1) * u + 1)
-                     ** (1 / (1 - a)) - 1)
-    ids = np.clip(ranks, 0, rows_per_field - 1).astype(np.int64)
-    lens = rng.integers(1, width + 1, (batch, n_fields))
+    ranks = np.floor(((rows ** (1 - a) - 1) * u + 1) ** (1 / (1 - a)) - 1)
+    ids = np.clip(ranks, 0, rows - 1).astype(np.int64)
+    lens = rng.integers(1, hot + 1, (batch, n_fields))
     mask = np.arange(width)[None, None, :] < lens[:, :, None]
     ids = np.where(mask, ids, -1)
     dense = rng.standard_normal((batch, max(n_dense, 1))).astype(np.float32)
@@ -89,26 +95,42 @@ def draw(rng, truth: PlantedTruth, model: dict, rows_per_field: int,
     return out
 
 
-def stream(model: dict, rows_per_field: int, mix: dict, batch: int, seed):
+def _per_field(values: list, shape: tuple):
+    """One number where every field has the same, else one per field,
+    shaped to broadcast over ``(B, F, L)``; both draw the same bits
+    (``bench/tests/test_sampler.py``). The scalar is kept for set-up:
+    numpy's scalar paths of ``clip`` and ``integers`` draw a 4096-sample
+    batch in 22 ms against 28 ms with per-field arrays at criteo-dlrm's
+    shapes, and 83 against 94 ms at kwai-dlrm's (one core of an Intel
+    Xeon, medians of 60 batches). Drop one branch once a configuration
+    with per-field values shows what the arrays cost there."""
+    if len(set(values)) == 1:
+        return values[0]
+    return np.array(values, np.int64).reshape(shape)
+
+
+def stream(config: dict, mix: dict, batch: int, seed):
     """Infinite generator of batches from one generator seeded with
     ``seed``: the program's own synthetic stream, draw for draw."""
-    t = truth(model, mix)
+    t = truth(config["model"], mix)
+    fields = spec.fields(config)
     rng = np.random.default_rng(seed)
     while True:
-        yield draw(rng, t, model, rows_per_field, mix, batch)
+        yield draw(rng, t, config["model"], fields, mix, batch)
 
 
-def batches(model: dict, rows_per_field: int, mix: dict, batch: int,
-            seed: int, start: int, n: int) -> list:
+def batches(config: dict, mix: dict, batch: int, seed: int, start: int,
+            n: int) -> list:
     """Batches ``start .. start + n - 1`` of the run's stream; batch ``i``
     is the first batch of ``stream(..., seed=[seed, i])``. Drawn on a few
     threads (numpy lets go of the interpreter lock inside its array
     operations)."""
-    t = truth(model, mix)
+    t = truth(config["model"], mix)
+    fields = spec.fields(config)
 
     def one(i):
-        return draw(np.random.default_rng([int(seed), int(i)]), t, model,
-                    rows_per_field, mix, batch)
+        return draw(np.random.default_rng([int(seed), int(i)]), t,
+                    config["model"], fields, mix, batch)
 
     workers = max(1, min(8, (os.cpu_count() or 1) - 1, n))
     with concurrent.futures.ThreadPoolExecutor(workers) as ex:
