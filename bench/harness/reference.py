@@ -17,11 +17,13 @@ logical id ``i`` of a field's table of ``R`` rows holding draw row
   leaves it and is applied by row-wise adagrad (accumulator += mean of the
   squared row gradient; row -= lr * g / sqrt(accumulator + eps)).
 
-Only the rows the compared batches touch are kept, so a table of any size
-costs one draw on the device and a gather. Float32 at the ``highest``
-matmul precision; ``dtype=bfloat16`` gives the control (everything held and
-computed in bf16), ``half_batch`` the planted fault that averages the loss
-over the first half of the batch only.
+Only the rows the compared batches touch are kept, each field's padded to
+a width of its own (``padded``), so a table of any size costs one draw on
+the device and a gather, and a field of wide bags costs no more than its
+own rows. Float32 at the ``highest`` matmul precision; ``dtype=bfloat16``
+gives the control (everything held and computed in bf16), ``half_batch``
+the planted fault that averages the loss over the first half of the batch
+only.
 """
 from __future__ import annotations
 
@@ -58,13 +60,16 @@ def touched(batches: list, n_fields: int) -> list:
 
 
 def padded(ids_per_field: list) -> list:
-    """The touched ids of every field padded with -1 to one length, a power
-    of two, so that every table and every seed reads and draws at one
-    shape."""
-    n = max(x.size for x in ids_per_field)
-    width = 1 << max(int(n - 1).bit_length(), 10)
-    return [np.concatenate([x, np.full(width - x.size, -1, np.int64)])
-            for x in ids_per_field]
+    """Each field's touched ids padded with -1 to a power of two of its own,
+    at least 1,024, so that a field's reads and draws change shape only
+    when its own touched count crosses a power of two, and a field of
+    100-id bags does not set the width of the 25 others."""
+    out = []
+    for x in ids_per_field:
+        width = 1 << max(int(x.size - 1).bit_length(), 10)
+        out.append(np.concatenate([x, np.full(width - x.size, -1,
+                                              np.int64)]))
+    return out
 
 
 class Reference:

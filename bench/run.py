@@ -156,9 +156,9 @@ def compared_steps(system, batches: list, n_fields: int) -> dict:
     """Step ``system`` through ``batches`` by the window's own call and read
     what the check compares: each step's loss, the first gradient as the
     optimizers received it, and the norm of each leaf's change (tower
-    leaves; the rows the batches touch in each table; and, of a host-backed
-    table, those of them gone from its device cache, read from its host
-    store)."""
+    leaves; the rows the batches touch in each table, read at that field's
+    own width, ``reference.padded``; and, of a host-backed table, those of
+    them gone from its device cache, read from its host store)."""
     import numpy as np
     from bench.harness import reference
     touched = reference.touched(batches, n_fields)

@@ -4,11 +4,14 @@ cells of ``BENCHMARK.json`` cut to a size the CPU runs in seconds.
     PYTHONPATH=src python -m pytest bench/tests -q
 """
 import copy
+import json
 import os
 import sys
 
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
+FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "fixtures")
 for p in (CHECKOUT, os.path.join(CHECKOUT, "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
@@ -38,6 +41,29 @@ def small_cell(name: str, fields: int = 3,
     cell.config = cfg
     cell.traffic = dict(cell.traffic, batch_per_chip=batch)
     return cell
+
+
+def fixture_cell(config: str, mix: str, batch: int, rows: int | None = None,
+                 fields: list | None = None, mix_keys: dict | None = None,
+                 limits: dict | None = None):
+    """A cell of a configuration and a mix under ``bench/tests/fixtures/``
+    (not in ``BENCHMARK.json``), on one chip with a small tower: only the
+    fields at the indices ``fields``, each table cut to ``rows`` rows."""
+    from bench.harness import spec
+    with open(os.path.join(FIXTURES, config + ".json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(FIXTURES, mix + ".json")) as f:
+        traffic = json.load(f)
+    if fields is not None:
+        cfg["fields"] = [cfg["fields"][i] for i in fields]
+    for f in cfg["fields"]:
+        f["rows"] = min(f["rows"], rows or f["rows"])
+    cfg["model"].update(n_id_fields=len(cfg["fields"]), mlp_dims=[64, 32])
+    if limits is not None:
+        cfg["check"]["limits"] = dict(limits)
+    traffic.update(mix_keys or {}, batch_per_chip=batch)
+    return spec.Cell(name=cfg["name"], chips=1, config=cfg, traffic=traffic,
+                     end_to_end=[], per_layer=[])
 
 
 CPU_PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}

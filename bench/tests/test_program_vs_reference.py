@@ -8,12 +8,14 @@ import pytest
 from bench import run as R
 from bench.harness import compare, program, reference, work
 
-from .conftest import CPU_LIMITS, CPU_PEAKS, small_cell
+from .conftest import CPU_LIMITS, CPU_PEAKS, fixture_cell, small_cell
 
 SEED = 2**31 + 101
 # the compared steps touch about 750 rows per table: the last four steps'
 # rows fit this cache, the twelve steps' do not
 CACHE_ROWS = 700
+# DLRM-DCNv2's fields, its bags of 1 to 100 ids each full
+DCN = ("dlrm-dcnv2", "zipf1.2-b4096-fixed-bags")
 
 
 def test_fused_dense_matches_reference():
@@ -157,3 +159,77 @@ def test_reference_reads_per_field_id_lists():
                                rtol=1e-6)
     for k, v in padded["change"].items():
         np.testing.assert_allclose(ragged["change"][k], v, rtol=1e-5)
+
+
+def one_width(ids_per_field: list) -> list:
+    """Every field's touched ids padded to the widest field's power of two,
+    as the harness padded them before each field had a width of its own."""
+    n = max(x.size for x in ids_per_field)
+    width = 1 << max(int(n - 1).bit_length(), 10)
+    return [np.concatenate([x, np.full(width - x.size, -1, np.int64)])
+            for x in ids_per_field]
+
+
+def test_padded_gives_each_field_its_own_width():
+    """Each field's touched ids, in order, then -1 up to the least power of
+    two that holds them, and never under 1,024."""
+    cell = fixture_cell(*DCN, batch=128, rows=20000)
+    ids = reference.touched(cell.batches(SEED, 0, 5), 26)
+    got = reference.padded(ids)
+    for x, p in zip(ids, got):
+        w = p.size
+        assert w & (w - 1) == 0 and w >= max(x.size, 1024)
+        assert w == 1024 or w < 2 * x.size
+        np.testing.assert_array_equal(p[:x.size], x)
+        assert np.all(p[x.size:] == -1)
+    sizes = [p.size for p in got]
+    assert min(sizes) == 1024 and max(sizes) >= 8192
+    assert sizes[20] == max(sizes)          # the field of 100-id bags
+
+
+def test_per_field_widths_read_as_one_width(monkeypatch):
+    """The reference's losses, first gradients and changes at each field's
+    own width are those at one width for every field: the padding rows are
+    drawn, never touched and never compared."""
+    cell = fixture_cell(*DCN, batch=128, rows=20000)
+    cfg = cell.config
+    batches = cell.batches(SEED, 0, cfg["check"]["steps"])
+    own = reference.Reference(cfg, SEED, cell.tower).run(batches)
+    monkeypatch.setattr(reference, "padded", one_width)
+    one = reference.Reference(cfg, SEED, cell.tower).run(batches)
+    np.testing.assert_allclose(own["losses"], one["losses"], rtol=1e-6)
+    for k in ("grad1", "change"):
+        assert own[k].keys() == one[k].keys()
+        for leaf, v in one[k].items():
+            np.testing.assert_allclose(own[k][leaf], v, rtol=1e-6,
+                                       err_msg=f"{k} {leaf}")
+
+
+def test_compared_steps_read_each_field_at_its_own_width():
+    """The program's rows are read at each field's own width, before and
+    after the compared steps and from a host store, and follow the
+    reference: DLRM-DCNv2's fields of 1, 100, 8 and 12 ids, every bag full,
+    the 8-id field host_lru behind a cache smaller than its touched rows."""
+    cell = fixture_cell(*DCN, batch=64, rows=20000, fields=[5, 20, 11, 19],
+                        mix_keys={"layout": "padded"}, limits=CPU_LIMITS)
+    cfg = cell.config
+    # eight steps touch ~850 rows of the 8-id field, any four in a row
+    # (the steps a put spends in the tau = 3 queue) under 570
+    cfg["fields"][2].update(rows=5000, backend="host_lru", cache_rows=640)
+    cfg["check"]["steps"] = 8
+    batches = cell.batches(SEED, 0, 8)
+    system = program.System(cfg, cell.batch)
+    system.init(SEED, batches[0])
+    widths, read = [], system.rows
+    system.rows = lambda ids: widths.append(
+        {n: len(x) for n, x in ids.items()}) or read(ids)
+    prog = R.compared_steps(system, batches, 4)
+    system.free()
+    assert len(widths) == 2 and widths[0] == widths[1]
+    assert sorted(set(widths[0].values())) == [1024, 2048, 8192]
+    assert len(prog["subsets"]["store/field_02"][1]) > 0
+    ref = reference.Reference(cfg, SEED, cell.tower).run(batches,
+                                                         prog["subsets"])
+    ok, checks = compare.verdict(compare.gaps(prog, ref),
+                                 cfg["check"]["limits"])
+    assert ok, checks

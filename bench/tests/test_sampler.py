@@ -143,3 +143,117 @@ def test_per_field_arrays_draw_the_scalar_bits(config, monkeypatch):
     for a, b in zip(scalar, arrays):
         for k in a:
             np.testing.assert_array_equal(a[k], b[k])
+
+
+DCN_CONFIG = ("tests", "fixtures", "dlrm-dcnv2.json")
+DCN_MIX = ("tests", "fixtures", "zipf1.2-b4096-fixed-bags.json")
+
+
+def padded_ids(ids: list) -> np.ndarray:
+    """Per-field ``(B, L_f)`` bags padded with -1 to one ``(B, F, L)``."""
+    width = max(x.shape[1] for x in ids)
+    return np.stack([np.pad(x, ((0, 0), (0, width - x.shape[1])),
+                            constant_values=-1) for x in ids], axis=1)
+
+
+def test_default_mix_keys_draw_the_same_bits():
+    """A mix that names the default layout and bags draws what a mix that
+    names neither draws."""
+    cfg = load("configs", "kwai-dlrm.json")
+    mix = load("traffic", "zipf1.2-b4096.json")
+    named = dict(mix, layout="padded", bags="ragged")
+    for a, b in zip(ctr_sampler.batches(cfg, mix, 64, 2**31 + 9, 0, 2),
+                    ctr_sampler.batches(cfg, named, 64, 2**31 + 9, 0, 2)):
+        for k in a:
+            np.testing.assert_array_equal(a[k], b[k])
+
+
+@pytest.mark.parametrize("config", ["criteo-dlrm", "kwai-dlrm"])
+def test_prob_sums_padded_bags_as_one_array(config):
+    """The truth read field by field gives the bits of the sum over the
+    whole ``(B, F, L)`` array, the labels' formula before per-field lists."""
+    cfg = load("configs", config + ".json")
+    mix = load("traffic", "zipf1.2-b4096.json")
+    truth = ctr_sampler.truth(cfg["model"], mix)
+    b = ctr_sampler.batches(cfg, mix, 512, 2**31 + 23, 0, 1)[0]
+    ids = b["ids"].astype(np.int64)
+    f = np.arange(ids.shape[1])[None, :, None]
+    bucket = np.where(ids >= 0, truth.w_buckets[f, np.where(ids >= 0, ids, 0)
+                                                % 256], 0.0).sum(-1)
+    nd = truth.w_dense.shape[0]
+    sig = (bucket @ truth.w_field) / np.sqrt(ids.shape[1]) \
+        + (b["dense"][:, :nd] @ truth.w_dense) / np.sqrt(nd)
+    np.testing.assert_array_equal(
+        truth.prob(b["ids"], b["dense"]),
+        1.0 / (1.0 + np.exp(-(sig - truth.bias))))
+
+
+@pytest.mark.parametrize("keys", [{"bags": "poisson"},
+                                  {"layout": "ragged_rows"},
+                                  {"layout": "per_field"},
+                                  {"layout": "per_field", "bags": "ragged"}])
+def test_mix_keys_outside_the_draws_raise(keys):
+    """Unknown values, and per-field lists with ragged bags, which no mix
+    draws, are refused."""
+    cfg = load(*DCN_CONFIG)
+    mix = {k: v for k, v in load(*DCN_MIX).items()
+           if k not in ("layout", "bags")}
+    with pytest.raises(ValueError):
+        ctr_sampler.batches(cfg, dict(mix, **keys), 8, 0, 0, 1)
+
+
+@pytest.mark.parametrize("seed", [3, 2**31 + 17])
+def test_per_field_layout_with_fixed_bags(seed):
+    """DLRM-DCNv2's traffic: one ``(B, hot_f)`` int32 array per field, every
+    bag full, every id inside its field's rows; the labels are the Bernoulli
+    draws of the planted truth, which reads the lists as it reads the same
+    ids padded to ``(B, F, L)``, to float32 rounding."""
+    cfg = load(*DCN_CONFIG)
+    mix = load(*DCN_MIX)
+    fields = spec.fields(cfg)
+    b = ctr_sampler.batches(cfg, mix, 256, seed, 5, 1)[0]
+    ids = b["ids"]
+    assert isinstance(ids, list) and len(ids) == 26
+    for x, f in zip(ids, fields):
+        assert x.dtype == np.int32 and x.shape == (256, f["hot"])
+        assert x.min() >= 0 and x.max() < f["rows"]
+    assert b["dense"].shape == (256, 13) and b["labels"].shape == (256, 1)
+    # the same draws, in the order the generator makes them
+    rng = np.random.default_rng([seed, 5])
+    for f in fields:
+        rng.random((256, f["hot"]))
+    dense = rng.standard_normal((256, 13)).astype(np.float32)
+    u = rng.random((256, 1))
+    truth = ctr_sampler.truth(cfg["model"], mix)
+    np.testing.assert_array_equal(b["dense"], dense)
+    prob = truth.prob(ids, dense)
+    np.testing.assert_allclose(prob, truth.prob(padded_ids(ids), dense),
+                               rtol=1e-6)
+    np.testing.assert_array_equal(b["labels"], (u < prob).astype(np.float32))
+
+
+def test_padded_layout_with_fixed_bags():
+    """Fixed bags in the padded layout the program takes: every bag full to
+    its field's hot, the tail -1, every id inside its field's rows."""
+    cfg = load(*DCN_CONFIG)
+    mix = dict(load(*DCN_MIX), layout="padded")
+    ids = ctr_sampler.batches(cfg, mix, 512, 11, 0, 1)[0]["ids"]
+    assert isinstance(ids, np.ndarray) and ids.shape == (512, 26, 100)
+    for f, field in enumerate(spec.fields(cfg)):
+        x = ids[:, f]
+        hot = field["hot"]
+        assert np.all(x[:, hot:] == -1) and x.max() < field["rows"]
+        assert np.all(x[:, :hot] >= 0)
+
+
+def test_per_field_layout_by_position():
+    """Batch ``i`` of a per-field stream is the same whatever stretch it is
+    drawn in, and differs from its neighbours."""
+    cfg = load(*DCN_CONFIG)
+    mix = load(*DCN_MIX)
+    ours = ctr_sampler.batches(cfg, mix, 64, 2**31 + 5, 2, 3)
+    again = ctr_sampler.batches(cfg, mix, 64, 2**31 + 5, 3, 1)[0]
+    for x, y in zip(ours[1]["ids"], again["ids"]):
+        np.testing.assert_array_equal(x, y)
+    np.testing.assert_array_equal(ours[1]["labels"], again["labels"])
+    assert not np.array_equal(ours[0]["ids"][20], ours[1]["ids"][20])
